@@ -101,26 +101,15 @@ def rules_for_config(cfg) -> ShardingRules:
 
 
 def active_mesh():
-    """The abstract mesh from ``jax.set_mesh``; None when not set.
-
-    Older jax releases predate ``get_abstract_mesh`` (and the AxisType
-    machinery); treat them as "no ambient mesh" so single-process paths
-    (serve/examples on CPU) still run.
-    """
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is None:
-        return None
-    mesh = get()
+    """The abstract mesh from ``jax.set_mesh``; None when not set."""
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
         return None
     return mesh
 
 
 def _mesh_axis_size(mesh, axis) -> int:
-    sizes = getattr(mesh, "axis_sizes", None)  # absent on old-jax Mesh
-    if sizes is None:
-        return dict(mesh.shape)[axis]
-    return dict(zip(mesh.axis_names, sizes))[axis]
+    return dict(zip(mesh.axis_names, mesh.axis_sizes))[axis]
 
 
 def _manual_axes(mesh) -> set:

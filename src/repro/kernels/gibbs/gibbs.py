@@ -14,13 +14,17 @@ chain (kernels/mh/mh.py).  Per half-sweep:
     sweep keeps every update's neighbourhood fixed, so all sites of one
     colour flip in parallel exactly as the macro's compartments do).
 
-Random inputs are kernel *operands* on CPU/interpret, exactly like the MH
-kernel; the in-kernel hw-PRNG variant remains TPU-only future work.
+``gibbs_chain_pallas`` takes the uniforms as (K, B, H, W) *operands*
+(host/cim randomness); ``gibbs_chain_pallas_fused`` draws them in-kernel
+from the counter cipher (kernels/rng), exactly like the fused MH kernel.
 
 Grid: (B,) — B independent lattices, one (H, W) block each.  W rides the
 128-wide lane axis; a periodic lattice cannot be zero-padded, so compiled
 TPU execution wants W as a lane multiple while interpret mode (CPU) takes
-any shape.
+any shape.  Per-lattice scalars ride whole (B,) arrays in SMEM.  A grid
+step holds the lattice plus K sample planes (and K uniform planes with
+operands) in VMEM: on v5e, 128x128 lattices compile at K = 32 in both
+modes, 256x256 only fused at K = 16, 512x512 not at all (DESIGN.md §3).
 """
 
 from __future__ import annotations
@@ -30,14 +34,20 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import rng
+
+# Per-lattice scalars (parity, key words, step base) travel as whole (B,)
+# arrays in SMEM, read at ``program_id(0)``: a (1, 1) VMEM block of a
+# (B, 1) array breaks the (8, 128) tiling rule of compiled TPU blocks.
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _gibbs_kernel(
     init_ref,     # (1, H, W) uint32 {0,1} spins
     u_ref,        # (K, 1, H, W) float32
-    parity_ref,   # (1, 1) int32 this lattice's starting parity
+    parity_ref,   # (B,) int32 SMEM: every lattice's starting parity
     *rest,        # n_consts broadcast model refs, then the two outputs:
                   #   samples (K, 1, H, W) uint32, flips (1, H, W) int32
     logit_fn,
@@ -47,7 +57,7 @@ def _gibbs_kernel(
     const_refs, (samples_ref, flips_ref) = rest[:n_consts], rest[n_consts:]
     consts = tuple(ref[...] for ref in const_refs)
     state0 = init_ref[0]
-    parity0 = parity_ref[0, 0]
+    parity0 = parity_ref[pl.program_id(0)]
     h, w = state0.shape
     row = jax.lax.broadcasted_iota(jnp.int32, (h, w), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (h, w), 1)
@@ -117,7 +127,7 @@ def gibbs_chain_pallas(
         in_specs=[
             pl.BlockSpec((1, h, w), lambda i: (i, 0, 0)),
             pl.BlockSpec((k_steps, 1, h, w), lambda i: (0, i, 0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            _SMEM,
             *const_specs,
         ],
         out_specs=[
@@ -129,15 +139,15 @@ def gibbs_chain_pallas(
             jax.ShapeDtypeStruct((b, h, w), jnp.int32),
         ],
         interpret=interpret,
-    )(init.astype(jnp.uint32), u, parity0b.reshape(b, 1), *consts)
+    )(init.astype(jnp.uint32), u, parity0b, *consts)
     return samples, flips
 
 
 def _gibbs_fused_kernel(
     init_ref,     # (1, H, W) uint32 {0,1} spins
-    k0_ref,       # (1, 1) uint32 this lattice's chain-key word 0
-    k1_ref,       # (1, 1) uint32 this lattice's chain-key word 1
-    t0_ref,       # (1, 1) int32 this lattice's absolute-step base
+    k0_ref,       # (B,) uint32 SMEM: per-lattice chain-key word 0
+    k1_ref,       # (B,) uint32 SMEM: per-lattice chain-key word 1
+    t0_ref,       # (B,) int32 SMEM: per-lattice absolute-step base
     *rest,        # n_consts broadcast model refs, then the two outputs:
                   #   samples (K, 1, H, W) uint32, flips (1, H, W) int32
     logit_fn,
@@ -161,14 +171,14 @@ def _gibbs_fused_kernel(
     const_refs, (samples_ref, flips_ref) = rest[:n_consts], rest[n_consts:]
     consts = tuple(ref[...] for ref in const_refs)
     state0 = init_ref[0]
-    k0 = k0_ref[0, 0]
-    k1 = k1_ref[0, 0]
-    t0 = t0_ref[0, 0].astype(jnp.uint32)
+    i = pl.program_id(0)
+    k0 = k0_ref[i]
+    k1 = k1_ref[i]
+    t0 = t0_ref[i].astype(jnp.uint32)
     h, w = state0.shape
     row = jax.lax.broadcasted_iota(jnp.int32, (h, w), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (h, w), 1)
     checker = (row + col) % 2
-    i = pl.program_id(0)
     site = ((i % lat_b) * h * w + row * w + col).astype(jnp.uint32)
 
     def body(k, carry):
@@ -235,9 +245,9 @@ def gibbs_chain_pallas_fused(
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, h, w), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            _SMEM,
+            _SMEM,
+            _SMEM,
             *const_specs,
         ],
         out_specs=[
@@ -251,9 +261,9 @@ def gibbs_chain_pallas_fused(
         interpret=interpret,
     )(
         init.astype(jnp.uint32),
-        k0b.reshape(b, 1),
-        k1b.reshape(b, 1),
-        t0b.astype(jnp.int32).reshape(b, 1),
+        k0b,
+        k1b,
+        t0b.astype(jnp.int32),
         *consts,
     )
     return samples, flips

@@ -11,14 +11,20 @@ state resident in VREG/VMEM — the TPU analogue of the paper's
   * only the kept sample stream is written back  (R/W circuits touched once
     per step instead of five times — same saving the paper measures).
 
-Random inputs (flip words, uniforms) are kernel *operands* on CPU/interpret;
-on real TPU hardware the `hw_prng` variant generates them in-kernel from the
-per-core PRNG (pltpu.prng_random_bits), restoring the paper's zero-traffic
-randomness.  (Verified: pltpu.prng_* does not lower in interpret mode, so
-that path is TPU-only and guarded.)
+Two kernels share that loop: ``mh_chain_pallas`` takes the random inputs
+(flip words, uniforms) as (K, B, C) *operands* (host/cim randomness), and
+``mh_chain_pallas_fused`` draws them in-kernel from the portable counter
+cipher (kernels/rng), restoring the paper's zero-traffic randomness on
+every substrate.  Both run compiled by Mosaic on TPU and in interpret mode
+elsewhere, bit-identical to the scan executor on the CPU.
 
 Grid: (B, C // BLOCK_C) — B independent targets (e.g. batch rows of logits),
 C chains per target ("compartments").  BLOCK_C rides the 128-wide lane axis.
+Every kernel value stays 2-D ``(1, BLOCK_C)`` (1-D vectors abort Mosaic's
+layout pass), and the table lookup is a one-hot compare-and-max because
+Mosaic has no lane gather (DESIGN.md §3).  The ``(1, BLOCK_C)`` row
+blocks meet the (8, 128) tiling rule only for B = 1, so B > 1 token
+tables do not compile for the chip yet.
 """
 
 from __future__ import annotations
@@ -32,8 +38,26 @@ from jax.experimental import pallas as pl
 from repro.kernels import rng
 
 
+def _lookup(table_col, words):
+    """``table[words]``, and -inf for words outside [0, V).
+
+    Mosaic lowers no lane gather over a (1, V) row, so the lookup is a
+    one-hot compare over a (V, BC) tile followed by a max over V: every
+    non-matching entry is -inf, so the max returns the matched value
+    bit for bit (-0.0 and -inf included), and a word with no match
+    (out of support) gets -inf — the ``TableTarget.log_prob`` semantics.
+    Costs V x BC compares per step; fine for the V=256 grid tables, and
+    vocabulary-width tables need V tiling instead."""
+    vocab = table_col.shape[0]
+    v = jax.lax.broadcasted_iota(jnp.int32, (vocab, words.shape[1]), 0)
+    hit = v == words.astype(jnp.int32)
+    return jnp.max(
+        jnp.where(hit, table_col, -jnp.inf), axis=0, keepdims=True
+    )
+
+
 def _mh_kernel(
-    table_ref,    # (1, V) float32
+    table_ref,    # (1, V, 1) float32
     init_ref,     # (1, BC) uint32
     flips_ref,    # (K, 1, BC) uint32
     u_ref,        # (K, 1, BC) float32
@@ -43,36 +67,29 @@ def _mh_kernel(
     nbits: int,
     n_steps: int,
 ):
-    table = table_ref[0, :]
-    vocab = table.shape[0]
+    table = table_ref[0]
     mask = jnp.uint32((1 << nbits) - 1)
-    state0 = init_ref[0, :]
-
-    def lookup(words):
-        safe = jnp.minimum(words, jnp.uint32(vocab - 1)).astype(jnp.int32)
-        vals = jnp.take(table, safe)
-        return jnp.where(words < vocab, vals, -jnp.inf)
-
-    logp0 = lookup(state0)
+    state0 = init_ref[...]
+    logp0 = _lookup(table, state0)
 
     def body(k, carry):
         state, logp, acc = carry
-        cand = jnp.bitwise_xor(state, flips_ref[k, 0, :] & mask)
-        logp_cand = lookup(cand)
+        cand = jnp.bitwise_xor(state, flips_ref[k] & mask)
+        logp_cand = _lookup(table, cand)
         delta = (logp_cand - logp).astype(jnp.float32)
         accept = jnp.logical_and(
-            u_ref[k, 0, :] < jnp.exp(jnp.minimum(delta, 0.0)),
+            u_ref[k] < jnp.exp(jnp.minimum(delta, 0.0)),
             jnp.isfinite(logp_cand),
         )
         state = jnp.where(accept, cand, state)       # in-memory copy
         logp = jnp.where(accept, logp_cand, logp)
-        samples_ref[k, 0, :] = state
+        samples_ref[k] = state
         return state, logp, acc + accept.astype(jnp.int32)
 
     _, _, acc = jax.lax.fori_loop(
         0, n_steps, body, (state0, logp0, jnp.zeros_like(state0, jnp.int32))
     )
-    accept_ref[0, :] = acc
+    accept_ref[...] = acc
 
 
 @functools.partial(
@@ -104,7 +121,7 @@ def mh_chain_pallas(
         kernel,
         grid=(b, c // block_c),
         in_specs=[
-            pl.BlockSpec((1, vocab), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, vocab, 1), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, block_c), lambda i, j: (i, j)),
             pl.BlockSpec((k_steps, 1, block_c), lambda i, j: (0, i, j)),
             pl.BlockSpec((k_steps, 1, block_c), lambda i, j: (0, i, j)),
@@ -118,12 +135,17 @@ def mh_chain_pallas(
             jax.ShapeDtypeStruct((b, c), jnp.int32),
         ],
         interpret=interpret,
-    )(table.astype(jnp.float32), init.astype(jnp.uint32), flips, u)
+    )(
+        table.astype(jnp.float32).reshape(b, vocab, 1),
+        init.astype(jnp.uint32),
+        flips,
+        u,
+    )
     return samples, accept
 
 
 def _mh_fused_kernel(
-    table_ref,    # (1, V) float32
+    table_ref,    # (1, V, 1) float32
     init_ref,     # (1, BC) uint32
     k0_ref,       # (1, BC) uint32 per-column chain-key word 0
     k1_ref,       # (1, BC) uint32 per-column chain-key word 1
@@ -149,27 +171,20 @@ def _mh_fused_kernel(
     is identical either way, so the stream is unchanged by
     construction.  ``cc`` is the per-chain column count (chains fold
     chain-major into the compartment axis, DESIGN.md §Chains-axis)."""
-    table = table_ref[0, :]
-    vocab = table.shape[0]
+    table = table_ref[0]
     mask = jnp.uint32((1 << nbits) - 1)
-    state0 = init_ref[0, :]
-    k0 = k0_ref[0, :]
-    k1 = k1_ref[0, :]
-    t0 = t0_ref[0, :].astype(jnp.uint32)
+    state0 = init_ref[...]
+    k0 = k0_ref[...]
+    k1 = k1_ref[...]
+    t0 = t0_ref[...].astype(jnp.uint32)
 
-    block_c = state0.shape[0]
+    block_c = state0.shape[1]
     i = pl.program_id(0)
     j = pl.program_id(1)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, block_c), 1)[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, block_c), 1)
     col = j * block_c + lane
     site = (i * cc + col % cc).astype(jnp.uint32)
-
-    def lookup(words):
-        safe = jnp.minimum(words, jnp.uint32(vocab - 1)).astype(jnp.int32)
-        vals = jnp.take(table, safe)
-        return jnp.where(words < vocab, vals, -jnp.inf)
-
-    logp0 = lookup(state0)
+    logp0 = _lookup(table, state0)
 
     def body(k, carry):
         state, logp, acc = carry
@@ -177,7 +192,7 @@ def _mh_fused_kernel(
         flip = rng.flips_at(s0, s1, site, nbits, p_u32)
         u = rng.uniform_at(s0, s1, site)
         cand = jnp.bitwise_xor(state, flip & mask)
-        logp_cand = lookup(cand)
+        logp_cand = _lookup(table, cand)
         delta = (logp_cand - logp).astype(jnp.float32)
         accept = jnp.logical_and(
             u < jnp.exp(jnp.minimum(delta, 0.0)),
@@ -185,13 +200,13 @@ def _mh_fused_kernel(
         )
         state = jnp.where(accept, cand, state)       # in-memory copy
         logp = jnp.where(accept, logp_cand, logp)
-        samples_ref[k, 0, :] = state
+        samples_ref[k] = state
         return state, logp, acc + accept.astype(jnp.int32)
 
     _, _, acc = jax.lax.fori_loop(
         0, n_steps, body, (state0, logp0, jnp.zeros_like(state0, jnp.int32))
     )
-    accept_ref[0, :] = acc
+    accept_ref[...] = acc
 
 
 @functools.partial(
@@ -240,7 +255,7 @@ def mh_chain_pallas_fused(
         kernel,
         grid=(b, c // block_c),
         in_specs=[
-            pl.BlockSpec((1, vocab), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, vocab, 1), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, block_c), lambda i, j: (i, j)),
             pl.BlockSpec((1, block_c), lambda i, j: (0, j)),
             pl.BlockSpec((1, block_c), lambda i, j: (0, j)),
@@ -256,7 +271,7 @@ def mh_chain_pallas_fused(
         ],
         interpret=interpret,
     )(
-        table.astype(jnp.float32),
+        table.astype(jnp.float32).reshape(b, vocab, 1),
         init.astype(jnp.uint32),
         k0c.reshape(1, c),
         k1c.reshape(1, c),
@@ -264,27 +279,3 @@ def mh_chain_pallas_fused(
     )
     return samples, accept
 
-
-def mh_chain_pallas_hwprng(*args, **kwargs):
-    """TPU-only variant that seeds pltpu's per-core hardware PRNG instead
-    of the portable counter cipher (``mh_chain_pallas_fused`` is the
-    production in-kernel-RNG path — same zero operand traffic, and its
-    stream is executor-portable).
-
-    pltpu.prng_seed/prng_random_bits have no CPU/interpret lowering
-    (verified NotImplementedError on this container) *and* draw from a
-    hardware stream the scan reference cannot reproduce, so this stays a
-    TPU-only stub.
-    """
-    if jax.default_backend() != "tpu":
-        raise NotImplementedError(
-            "hw_prng MH kernel requires a TPU backend; use "
-            "mh_chain_pallas_fused (portable in-kernel counter RNG) or "
-            "mh_chain_pallas with explicit randomness operands."
-        )
-    raise NotImplementedError(
-        "TPU hw-PRNG path: seed pltpu.prng_seed(seed + program_id), draw "
-        "nbits random words per step, threshold at p_bfr * 2^32, pack bit "
-        "planes, and XOR-fold 2^stages draws for u. Not reachable in this "
-        "CPU container."
-    )

@@ -110,9 +110,12 @@ def raw_draw(s0, s1, site, salt: int) -> jnp.ndarray:
 def uniform_at(s0, s1, site) -> jnp.ndarray:
     """u ~ U[0,1) at each ``site``: the top 24 bits of the U-stream draw,
     scaled — (bits >> 8) < 2^24 is exactly representable in float32, so
-    the conversion is deterministic across executors."""
+    the conversion is deterministic across executors.  It goes through
+    int32 (exact below 2^31) because Mosaic has no uint32 -> float32
+    cast."""
     bits = raw_draw(s0, s1, site, U_SALT)
-    return (bits >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(
+    top = (bits >> jnp.uint32(8)).astype(jnp.int32)
+    return top.astype(jnp.float32) * jnp.float32(
         1.0 / (1 << 24)
     )
 

@@ -23,20 +23,10 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-
-try:  # AxisType only exists from jax 0.4.3x; the pinned-min CI cell
-    from jax.sharding import AxisType  # (0.4.30) must still import us
-except ImportError:  # pragma: no cover - version-dependent
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
-    if AxisType is None:
-        raise RuntimeError(
-            "make_production_mesh needs jax >= 0.4.35 (jax.make_mesh / "
-            "AxisType); the sampler meshes (make_chains_mesh) support the "
-            "full pinned range"
-        )
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
@@ -44,10 +34,6 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 def alt_mesh(data: int, model: int, *, pods: int = 1):
     """Same-chip-count §Perf variants, e.g. alt_mesh(32, 8)."""
-    if AxisType is None:
-        raise RuntimeError(
-            "alt_mesh needs jax >= 0.4.35 (jax.make_mesh / AxisType)"
-        )
     if pods > 1:
         return jax.make_mesh(
             (pods, data, model),
@@ -69,10 +55,6 @@ def make_chains_mesh(num_chains: int | None = None, *, devices=None):
     turns one CPU into N host devices).  Returns ``None`` when sharding
     cannot help — fewer than 2 devices, or a known chain count below 2 —
     so callers can pass the result straight to ``RunPlan(mesh=...)``.
-
-    Built via the ``jax.sharding.Mesh`` constructor directly:
-    ``jax.make_mesh`` only exists from jax 0.4.35, and this must run on
-    the whole supported range (pyproject pins >= 0.4.30).
     """
     if num_chains is not None and num_chains < 2:
         return None

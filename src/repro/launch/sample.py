@@ -393,4 +393,7 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     main()

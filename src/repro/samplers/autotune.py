@@ -36,7 +36,12 @@ import time
 import jax
 
 from repro import telemetry
-from repro.samplers.engine import EngineConfig, MHEngine, resolve_execution
+from repro.samplers.engine import (
+    EngineConfig,
+    IneligibleExecution,
+    MHEngine,
+    resolve_execution,
+)
 from repro.samplers.plan import RunPlan
 
 CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
@@ -123,7 +128,7 @@ def _eligible_executions(config: EngineConfig, target) -> list[str]:
     try:
         resolve_execution("pallas", target, config.update)
         out.append("pallas")
-    except ValueError:
+    except IneligibleExecution:
         pass
     return out
 
@@ -134,8 +139,8 @@ def measure_config(
 ) -> float:
     """Best-of-N steps/s of one candidate config — the bench harness
     protocol (warm-up pays the compile; the minimum tracks compute on a
-    loaded machine).  Raises whatever the engine raises on an ineligible
-    candidate (shape/backend) — callers filter."""
+    loaded machine).  Raises ``IneligibleExecution`` on a candidate the
+    engine refuses (shape/backend) — callers filter."""
     engine = MHEngine(config)
     plan = RunPlan(
         target=target,
@@ -174,8 +179,10 @@ def autotune_config(
     Cache hit: returns the stored winner without measuring.  Miss (or
     ``refresh=True``): measures the candidate grid — incumbent first, so
     the argmax can never lose to it — stores, and returns.  Candidates
-    the engine rejects (pallas on an unfusable target/shape) are
-    silently dropped; the incumbent itself failing is an error.
+    the engine rejects (``IneligibleExecution``: pallas on an unfusable
+    target/shape) are dropped; the incumbent itself failing is an
+    error, and so is any other failure — a kernel the compiler refuses
+    must not quietly lose to scan.
     """
     path = cache_path if cache_path is not None else default_cache_path()
     ckey = tune_key(config, target, init_words)
@@ -236,7 +243,7 @@ def autotune_config(
                     cand_cfg, target, init_words, key=key, n_steps=n_steps,
                     repeats=repeats,
                 )
-            except Exception:
+            except IneligibleExecution:
                 sp.set(outcome="ineligible")
                 if i == 0:  # the incumbent must run — no fallback
                     raise

@@ -69,6 +69,13 @@ _EXECUTION_CHOICES = ("auto", "scan", "pallas")
 _UPDATE_CHOICES = ("mh", "gibbs")
 
 
+class IneligibleExecution(ValueError):
+    """The engine refuses an execution for this target or state layout
+    (pallas on an unfusable target, a chain state the kernels' grid
+    cannot take).  The autotuner drops exactly these candidates; any
+    other error, a compiler's refusal included, propagates."""
+
+
 def parse_collect(collect: str) -> tuple[str, int]:
     """Validate a collection spec; returns ``(mode, k)``.
 
@@ -198,7 +205,7 @@ def resolve_execution(execution: str, target, update: str = "mh") -> str:
     if update == "gibbs":
         if execution == "pallas":
             if not getattr(target, "supports_fused_gibbs", False):
-                raise ValueError(
+                raise IneligibleExecution(
                     "pallas Gibbs execution needs a lattice model with a "
                     "fused checkerboard kernel (supports_fused_gibbs); "
                     "use execution='scan'"
@@ -210,7 +217,7 @@ def resolve_execution(execution: str, target, update: str = "mh") -> str:
         return "scan"
     if execution == "pallas":
         if target.table is None:
-            raise ValueError(
+            raise IneligibleExecution(
                 "pallas execution needs a table target (the fused kernel "
                 "holds the distribution in VMEM); use a TableTarget or "
                 "execution='scan'"
@@ -446,7 +453,7 @@ def _run_pallas(
     from repro.kernels.mh import ops as mh_ops  # avoid import cycle
 
     if init_words.ndim != 2:
-        raise ValueError(
+        raise IneligibleExecution(
             f"pallas execution expects (B, C) chain state, got {init_words.shape}"
         )
     step0 = _step0_base(step0)
@@ -519,7 +526,7 @@ def _run_pallas_gibbs(
     from repro.kernels.gibbs import ops as gibbs_ops  # avoid import cycle
 
     if init_words.ndim != 3:
-        raise ValueError(
+        raise IneligibleExecution(
             f"pallas Gibbs expects (B, H, W) lattice state, got "
             f"{init_words.shape}"
         )
@@ -578,7 +585,7 @@ def _run_pallas_chains(
     from repro.kernels.mh import ops as mh_ops  # avoid import cycle
 
     if init.ndim != 3:
-        raise ValueError(
+        raise IneligibleExecution(
             f"multi-chain pallas execution expects (num_chains, B, C) chain "
             f"state, got {init.shape}"
         )
@@ -639,7 +646,7 @@ def _run_pallas_gibbs_chains(
     from repro.kernels.gibbs import ops as gibbs_ops  # avoid import cycle
 
     if init.ndim != 4:
-        raise ValueError(
+        raise IneligibleExecution(
             f"multi-chain pallas Gibbs expects (num_chains, B, H, W) lattice "
             f"state, got {init.shape}"
         )
@@ -692,25 +699,25 @@ def _shard_over_chains(body, mesh, num_chains: int, n_out: int):
     chain count the mesh doesn't divide runs replicated (unsharded) rather
     than padded, and a mesh-less call is the identity.  Chains never
     communicate, so the sharded program is collective-free and
-    bit-identical to the unsharded one.
+    bit-identical to the unsharded one.  The wrapper is jitted: run
+    eagerly, shard_map rejects an output the body builds from constants
+    alone (the empty ``samples`` of ``collect="last"``).
     """
     if mesh is None:
         return body
-    from jax.experimental.shard_map import shard_map
-
     from repro.distributed import sharding
 
     spec = sharding.spec_for(("chains",), shape=(num_chains,), mesh=mesh)
     if spec is None or len(spec) == 0 or spec[0] is None:
         return body
     p = jax.sharding.PartitionSpec(spec[0])
-    return shard_map(
+    return jax.jit(jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(p, p),
         out_specs=tuple(p for _ in range(n_out)),
-        check_rep=False,
-    )
+        check_vma=False,
+    ))
 
 
 class MHEngine:

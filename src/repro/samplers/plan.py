@@ -60,10 +60,7 @@ def _host_side() -> bool:
     only read python ints safely) at the host level; traced re-entries
     (the serving tier's vmapped advance, tempering's jitted segments)
     skip instrumentation entirely."""
-    try:
-        return jax.core.trace_state_clean()
-    except AttributeError:  # pragma: no cover - newer jax moved it
-        return True
+    return jax.core.trace_ctx.is_top_level()
 
 
 def carries_logp(engine: "MHEngine", target) -> bool:
@@ -332,16 +329,6 @@ def _submit_compiled_logp(
     )
 
 
-def _jit_cache_size(fn) -> int | None:
-    """Trace-cache entry count of a jitted callable (None when the jax
-    version hides it) — how the submit span tells a compile apart from a
-    cached re-dispatch."""
-    try:
-        return fn._cache_size()
-    except Exception:  # pragma: no cover - older/newer jax internals
-        return None
-
-
 def _submit_span(engine: MHEngine, plan: RunPlan, compiled: bool):
     """The ``engine.submit`` telemetry span (DESIGN.md §Telemetry).
     Host-side calls only — inside a jax trace the span would time trace
@@ -410,11 +397,11 @@ def submit(engine: MHEngine, plan: RunPlan, *, compiled: bool = False):
             result = dispatcher(*args, **kw)
         else:
             with span as sp:
-                before = _jit_cache_size(dispatcher)
+                # the trace-cache entry count grows iff this call compiled
+                before = dispatcher._cache_size()
                 result = dispatcher(*args, **kw)
-                after = _jit_cache_size(dispatcher)
-                if before is not None and after is not None:
-                    sp.set(jit_cache="miss" if after > before else "hit")
+                after = dispatcher._cache_size()
+                sp.set(jit_cache="miss" if after > before else "hit")
     else:
         run_args = (key, plan.target, plan.n_steps, plan.init_words)
         run_kw = dict(

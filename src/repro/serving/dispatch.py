@@ -53,17 +53,6 @@ from repro.samplers.engine import (
 )
 
 
-def jit_cache_size(fn) -> int:
-    """Compiled-program count of a jitted callable (0 when unknown) —
-    the serving tier's compiled-programs-per-burst telemetry reads the
-    delta across a burst, the same ``_cache_size`` verdict the Run-API's
-    ``jit_cache`` span metadata is built on (samplers/plan.py)."""
-    try:
-        return int(fn._cache_size())
-    except Exception:
-        return 0
-
-
 def poison_donated(*arrays) -> None:
     """Make the donation contract loud: delete the carry buffers that
     were just donated to an advance program.
@@ -99,20 +88,18 @@ def _slot_axis_wrap(mesh, n_slots: int, n_in: int, n_out: int):
     """
     if mesh is None:
         return lambda body: body
-    from jax.experimental.shard_map import shard_map
-
     from repro.distributed import sharding
 
     spec = sharding.spec_for(("chains",), shape=(n_slots,), mesh=mesh)
     if spec is None or len(spec) == 0 or spec[0] is None:
         return lambda body: body
     p = jax.sharding.PartitionSpec(spec[0])
-    return lambda body: shard_map(
+    return lambda body: jax.shard_map(
         body,
         mesh=mesh,
         in_specs=tuple(p for _ in range(n_in)),
         out_specs=tuple(p for _ in range(n_out)),
-        check_rep=False,
+        check_vma=False,
     )
 
 

@@ -481,7 +481,7 @@ class PackedExecutor:
         return collect, step0s, keys
 
     def _count_compiles(self, before: int) -> None:
-        grew = dispatch.jit_cache_size(self._advance) - before
+        grew = self._advance._cache_size() - before
         if grew > 0:
             self.advance_compiles += grew
             telemetry.counter(
@@ -498,7 +498,7 @@ class PackedExecutor:
             [s.member.index if s else 0 for s in self._slots], jnp.int32
         )
         old_words, old_logp = self.words, self.logp
-        before = dispatch.jit_cache_size(self._advance)
+        before = self._advance._cache_size()
         samples, words, logp, acc = self._advance(
             old_words, old_logp, keys, step0s, tidx, seg=seg, collect=collect
         )
@@ -526,7 +526,7 @@ class PackedExecutor:
         (dispatch.make_pallas_advance_fn).  No per-slot fallback."""
         collect, step0s, keys = self._segment_inputs(active)
         old_words = self.words
-        before = dispatch.jit_cache_size(self._advance)
+        before = self._advance._cache_size()
         samples, words, logp, acc = self._advance(
             old_words, keys, step0s, seg=seg, collect=collect
         )

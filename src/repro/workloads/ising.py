@@ -70,7 +70,8 @@ class IsingModel:
         scan executor steps it and the fused kernel traces it (it rides
         a jit static argument, hence the frozen dataclass).
         """
-        s = 2.0 * state.astype(jnp.float32) - 1.0
+        # {0, 1} words via int32: Mosaic has no uint32 -> float32 cast
+        s = 2.0 * state.astype(jnp.int32).astype(jnp.float32) - 1.0
         nb = (
             jnp.roll(s, 1, -2)
             + jnp.roll(s, -1, -2)

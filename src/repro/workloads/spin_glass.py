@@ -131,7 +131,8 @@ class SpinGlass:
     def fused_logit(self, state: Array, j_right, j_down) -> Array:
         """Per-site logit of s_i = +1 given the neighbours:
         2 (sum_j J_ij s_j + field), each incident bond with its own J."""
-        s = 2.0 * state.astype(jnp.float32) - 1.0
+        # {0, 1} words via int32: Mosaic has no uint32 -> float32 cast
+        s = 2.0 * state.astype(jnp.int32).astype(jnp.float32) - 1.0
         nb = (
             j_right * jnp.roll(s, -1, -1)
             + jnp.roll(j_right, 1, -1) * jnp.roll(s, 1, -1)

@@ -6,23 +6,3 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-# hypothesis is a dev extra; when absent, only the property-based tests
-# skip — plain tests in the same modules keep running.
-try:
-    from hypothesis import given, settings, strategies as st  # noqa: F401
-except ImportError:
-    import pytest
-
-    _skip = pytest.mark.skip(reason="hypothesis not installed")
-
-    def given(*_args, **_kwargs):
-        return lambda f: _skip(f)
-
-    def settings(*_args, **_kwargs):
-        return lambda f: f
-
-    class _StrategyStub:
-        def __getattr__(self, _name):
-            return lambda *a, **k: None
-
-    st = _StrategyStub()

@@ -229,8 +229,6 @@ class TestShardedChains:
         from repro.workloads.ising import IsingModel
 
         assert jax.device_count() == 2, jax.devices()
-        # jax.sharding.Mesh directly: jax.make_mesh needs >= 0.4.35 and
-        # this must pass on the pinned-min (0.4.30) CI cell
         mesh = jax.sharding.Mesh(np.asarray(jax.devices()), ("data",))
         key = jax.random.PRNGKey(7)
 

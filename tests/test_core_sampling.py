@@ -4,8 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-from conftest import given, settings, st  # hypothesis or skip-stubs
+from hypothesis import given, settings, strategies as st
 
 from repro.core import metropolis, proposal, targets, uniform_rng
 from repro.core.macro import CIMMacro, MacroConfig

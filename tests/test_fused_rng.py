@@ -28,8 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-from conftest import given, settings, st  # hypothesis or skip-stubs
+from hypothesis import given, settings, strategies as st
 
 from repro import samplers, tempering
 from repro.kernels import rng

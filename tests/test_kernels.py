@@ -8,8 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-from conftest import given, settings, st  # hypothesis or skip-stubs
+from hypothesis import given, settings, strategies as st
 
 from repro.core import bitcell
 from repro.kernels.mh import ops as mh_ops

@@ -102,6 +102,12 @@ class TestShardedParity:
         b = geng.submit(gplan).result
         np.testing.assert_array_equal(
             np.asarray(a.samples), np.asarray(b.samples))
+        # collect="last": the body's empty sample block is a constant
+        a = geng.submit(gplan.replace(mesh=mesh, collect="last")).result
+        b = geng.submit(gplan.replace(collect="last")).result
+        assert a.samples.shape[1] == 0
+        np.testing.assert_array_equal(
+            np.asarray(a.final_words), np.asarray(b.final_words))
         print("SHARDED-4-OK")
         """)
         assert "SHARDED-4-OK" in out

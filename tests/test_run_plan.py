@@ -279,6 +279,35 @@ class TestAutotune:
         assert res2.source == "cache"
         assert tuned2 == tuned_cfg
 
+    @pytest.mark.parametrize("dropped", [True, False])
+    def test_only_ineligible_candidates_drop(self, tmp_path, monkeypatch,
+                                              dropped):
+        """A candidate the engine refuses (IneligibleExecution) is
+        dropped; any other failure, e.g. a compiler refusing a kernel,
+        propagates instead of quietly losing to the incumbent."""
+        from repro.samplers import autotune
+        from repro.samplers.engine import IneligibleExecution
+
+        real = autotune.measure_config
+        err = IneligibleExecution if dropped else RuntimeError
+
+        def measure(cfg, *a, **k):
+            if cfg.chunk_steps == 16:
+                raise err("refused")
+            return real(cfg, *a, **k)
+
+        monkeypatch.setattr(autotune, "measure_config", measure)
+        target, init = _mh_setup(c=16)
+        kw = dict(n_steps=32, repeats=1, chunk_candidates=(16,),
+                  cache_path=str(tmp_path / "c.json"))
+        cfg = samplers.EngineConfig(chunk_steps=32, execution="scan")
+        if dropped:
+            _, res = samplers.autotune_config(cfg, target, init, **kw)
+            assert all(c[0] != 16 for c in res.candidates)
+        else:
+            with pytest.raises(RuntimeError, match="refused"):
+                samplers.autotune_config(cfg, target, init, **kw)
+
     def test_cache_key_separates_shapes(self, tmp_path):
         target, init = _mh_setup(c=8)
         cfg = samplers.EngineConfig()

@@ -2,12 +2,6 @@
 
 import jax
 import pytest
-
-if not hasattr(jax.sharding, "AxisType"):
-    pytest.skip(
-        "jax.sharding.AxisType unavailable on this jax version",
-        allow_module_level=True,
-    )
 from jax.sharding import AbstractMesh, AxisType, PartitionSpec as P
 
 from repro.distributed.sharding import (

@@ -407,6 +407,29 @@ class TestInstrumentation:
         ]
         assert verdicts == ["miss", "hit"]
 
+    def test_submit_inside_a_trace_emits_no_span(self):
+        """Spans are host-side only: a submit re-entered inside a jax
+        trace (as serving's class programs and tempering's segments do)
+        records nothing."""
+        from repro.samplers.plan import _host_side
+
+        target, init = _mh_setup()
+        engine = samplers.MHEngine(samplers.EngineConfig(chunk_steps=8))
+        inside = []
+
+        @jax.jit
+        def run(words):
+            inside.append(_host_side())
+            plan = samplers.RunPlan(
+                target=target, n_steps=12, init_words=words, seed=2
+            )
+            return engine.submit(plan).result.final_words
+
+        tr = telemetry.enable()
+        run(init)
+        assert _host_side() and inside == [False]
+        assert not [e for e in tr.events() if e.name == "engine.submit"]
+
     def test_run_resumable_emits_segment_logs(self, tmp_path):
         target, init = _mh_setup()
         engine = samplers.MHEngine(samplers.EngineConfig(chunk_steps=8))
