@@ -1,0 +1,11 @@
+"""Roofline share of the Gibbs sampling step in a batch cell, in %
+(see ``_roofline.py``); read where the update rule is Gibbs."""
+
+import lib
+from pathlib import Path
+
+_share = lib.load_module(Path(__file__).with_name("_roofline.py")).share
+
+
+def read(r):
+    return _share(r) if r.cfg.get("update") == "gibbs" else None
