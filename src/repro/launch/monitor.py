@@ -3,8 +3,9 @@
 The read-side companion of ``--trace`` (DESIGN.md §Telemetry): point it
 at a JSONL trace emitted by ``launch/sample``, ``launch/serve_engine``
 or ``benchmarks/run`` and get a per-span-name aggregation (count, total
-/ mean / max duration, share of traced time) plus the instant/log
-events.  ``--check`` validates every line against the trace event
+/ mean / max duration, and share of traced time by self time, so a
+nested span is not counted twice) plus the instant/log events.
+``--check`` validates every line against the trace event
 schema and exits non-zero on the first malformed file — the CI
 telemetry smoke runs exactly this.  ``--follow`` tails a live file,
 printing events as a run appends them.
@@ -68,20 +69,55 @@ def read_events(path: str) -> tuple[dict | None, list[dict]]:
     return header, events
 
 
+def _self_times(spans: list[dict]) -> tuple[list[float], float]:
+    """Each span's self time in µs: its duration less its children's,
+    the spans one level deeper on the same ``tid`` that start inside it.
+    Also the time of the top-level spans, those with no parent among
+    ``spans``; the self times sum to it."""
+
+    keys = [
+        (ev.get("tid", 0), float(ev.get("ts_us", 0.0)), ev.get("depth", 0))
+        for ev in spans
+    ]
+    durs = [float(ev.get("dur_us", 0.0)) for ev in spans]
+    own = list(durs)
+    top = 0.0
+    stack: list[int] = []      # open spans of one tid, outermost first
+    for i in sorted(range(len(spans)), key=keys.__getitem__):
+        tid, t0, depth = keys[i]
+        while stack:
+            p_tid, p_t0, p_depth = keys[stack[-1]]
+            if p_tid == tid and p_depth < depth and t0 < p_t0 + durs[stack[-1]]:
+                break
+            stack.pop()
+        if stack and keys[stack[-1]][2] == depth - 1:
+            own[stack[-1]] -= durs[i]
+        else:
+            top += durs[i]
+        stack.append(i)
+    return own, top
+
+
 def summarize_events(events: list[dict], top: int = 20) -> list[dict]:
-    """Per-span-name aggregate rows, sorted by total duration."""
+    """Per-span-name aggregate rows, sorted by total duration.
+
+    ``total_ms`` is each name's summed duration; ``share`` is its self
+    time (children's time taken out, so a nested span is not counted
+    twice) over the top-level time, so the shares add up to 1."""
+    spans = [ev for ev in events if ev.get("kind") == "span"]
+    own, top_us = _self_times(spans)
     agg: dict[str, dict] = {}
-    for ev in events:
-        if ev.get("kind") != "span":
-            continue
+    for ev, self_us in zip(spans, own):
         row = agg.setdefault(
-            ev["name"], {"count": 0, "total_us": 0.0, "max_us": 0.0}
+            ev["name"],
+            {"count": 0, "total_us": 0.0, "self_us": 0.0, "max_us": 0.0},
         )
         dur = float(ev.get("dur_us", 0.0))
         row["count"] += 1
         row["total_us"] += dur
+        row["self_us"] += self_us
         row["max_us"] = max(row["max_us"], dur)
-    total = sum(r["total_us"] for r in agg.values()) or 1.0
+    total = top_us or 1.0
     rows = []
     for name, r in sorted(
         agg.items(), key=lambda kv: -kv[1]["total_us"]
@@ -93,7 +129,7 @@ def summarize_events(events: list[dict], top: int = 20) -> list[dict]:
                 "total_ms": round(r["total_us"] / 1e3, 3),
                 "mean_us": round(r["total_us"] / r["count"], 1),
                 "max_us": round(r["max_us"], 1),
-                "share": round(r["total_us"] / total, 3),
+                "share": round(r["self_us"] / total, 3),
             }
         )
     return rows

@@ -55,6 +55,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import telemetry
 from repro.samplers.randomness import (
     RandomnessBackend,
     chain_key,
@@ -230,6 +231,24 @@ def resolve_execution(execution: str, target, update: str = "mh") -> str:
     return "scan"
 
 
+def _host_side() -> bool:
+    """True outside any jax trace — telemetry spans only make sense (and
+    only read python ints safely) at the host level; traced re-entries
+    (the serving tier's vmapped advance, tempering's jitted segments,
+    ``run_engine`` and the compiled submit) skip instrumentation
+    entirely."""
+    return jax.core.trace_ctx.is_top_level()
+
+
+def _host_spans(traced: bool = False):
+    """``telemetry.span`` for a call that runs on the host with tracing
+    on, else the no-op ``telemetry.null_span`` — decided once per call,
+    so a loop pays no per-iteration check."""
+    if telemetry.enabled() and not traced and _host_side():
+        return telemetry.span
+    return telemetry.null_span
+
+
 def _mh_step(target, nbits: int, words, logp, acc, flip, u):
     """THE MH step — the only scan-side implementation in the repo.
 
@@ -237,16 +256,18 @@ def _mh_step(target, nbits: int, words, logp, acc, flip, u):
     op-for-op: XOR-propose, table/fn lookup, u < exp(min(dlogp, 0))
     accept, select (in-memory copy).
     """
-    mask = jnp.uint32((1 << nbits) - 1)
-    cand = jnp.bitwise_xor(words, flip & mask)
-    logp_cand = target.log_prob(cand).astype(jnp.float32)
-    delta = logp_cand - logp
-    accept = jnp.logical_and(
-        u < jnp.exp(jnp.minimum(delta, 0.0)), jnp.isfinite(logp_cand)
-    )
-    words = jnp.where(accept, cand, words)        # in-memory copy
-    logp = jnp.where(accept, logp_cand, logp)
-    return words, logp, acc + accept.astype(jnp.int32)
+    with jax.named_scope("mh.propose"):
+        mask = jnp.uint32((1 << nbits) - 1)
+        cand = jnp.bitwise_xor(words, flip & mask)
+        logp_cand = target.log_prob(cand).astype(jnp.float32)
+        delta = logp_cand - logp
+        accept = jnp.logical_and(
+            u < jnp.exp(jnp.minimum(delta, 0.0)), jnp.isfinite(logp_cand)
+        )
+    with jax.named_scope("mh.select"):
+        words = jnp.where(accept, cand, words)        # in-memory copy
+        logp = jnp.where(accept, logp_cand, logp)
+        return words, logp, acc + accept.astype(jnp.int32)
 
 
 def _run_scan_chunked(make_xs, step_fn, carry, n_steps, chunk, step0, collect):
@@ -316,7 +337,8 @@ def _run_scan(
     )
 
     def make_xs(start, n):
-        return backend.chunk(key, start, n, shape, nbits)
+        with jax.named_scope("mh.draw"):
+            return backend.chunk(key, start, n, shape, nbits)
 
     def step_fn(c, x):
         flip, u = x
@@ -361,13 +383,19 @@ def _chunk_writer(ndim: int):
     return jax.jit(write, donate_argnums=(0,))
 
 
-def _drive_pallas_chunks(run_chunk, init_state, n_steps, chunk, step0, collect):
+def _drive_pallas_chunks(
+    run_chunk, init_state, n_steps, chunk, step0, collect, draw=None,
+    randomness=None,
+):
     """THE fused-executor chunk scheduler — the python chunk loop all four
     pallas executors share.
 
-    ``run_chunk(state, start, n)`` launches one fused-kernel program for
-    relative steps [start, start + n) and returns (samples (n, *state
-    shape) uint32, per-site count (*state shape) int32).  Kept rows are
+    ``run_chunk(state, start, n, *operands)`` launches one kernel program
+    for relative steps [start, start + n) and returns (samples (n, *state
+    shape) uint32, per-site count (*state shape) int32).  ``draw(start,
+    n)`` returns the chunk's randomness operands for the operand kernels
+    (``randomness`` names the backend); fused randomness draws in the
+    kernel and passes no ``draw``.  Kept rows are
     written straight into one preallocated output buffer via
     ``lax.dynamic_update_slice``: under a trace (``run_engine`` or any
     caller-side jit — which also collapses the loop into a single
@@ -380,6 +408,13 @@ def _drive_pallas_chunks(run_chunk, init_state, n_steps, chunk, step0, collect):
     loop) is static; ``step0`` may be traced (``_step0_base``) except
     under thinning, whose kept-slice arithmetic is python-level
     (enforced upstream by ``_parse_collect``).
+
+    Telemetry (DESIGN.md §Telemetry): a host-side loop with tracing on
+    records one ``engine.chunk`` span per chunk, holding a
+    ``randomness.draw`` span around the operand draw and an
+    ``engine.emit`` span around the kept-row write and the state/count
+    glue; the caller's ``engine.finish`` covers the job's tail.  Under a
+    trace the loop is staged, not run, and records nothing.
     """
     mode, k = collect
     chunk = _effective_chunk(n_steps, chunk, k if mode == "thin" else None)
@@ -392,6 +427,7 @@ def _drive_pallas_chunks(run_chunk, init_state, n_steps, chunk, step0, collect):
     else:
         n_keep = 0
     traced = isinstance(state, jax.core.Tracer)
+    span = _host_spans(traced)
     out = jnp.zeros((n_keep, *state.shape), jnp.uint32)
     zeros = (0,) * state.ndim
     pos = 0
@@ -406,15 +442,21 @@ def _drive_pallas_chunks(run_chunk, init_state, n_steps, chunk, step0, collect):
 
     for start in range(0, n_steps, chunk):
         n = min(chunk, n_steps - start)
-        samples, a = run_chunk(state, start, n)
-        state = samples[-1]
-        acc = acc + a
-        if mode == "all":
-            emit(samples)
-        elif mode == "thin":
-            i0 = _thin_offset(step0 + start, k)
-            if i0 < n:
-                emit(samples[i0::k])
+        with span("engine.chunk", start=start, n=n):
+            operands = ()
+            if draw is not None:
+                with span("randomness.draw", backend=randomness, n=n):
+                    operands = draw(start, n)
+            samples, a = run_chunk(state, start, n, *operands)
+            with span("engine.emit"):
+                state = samples[-1]
+                acc = acc + a
+                if mode == "all":
+                    emit(samples)
+                elif mode == "thin":
+                    i0 = _thin_offset(step0 + start, k)
+                    if i0 < n:
+                        emit(samples[i0::k])
     return out, acc, state
 
 
@@ -458,6 +500,7 @@ def _run_pallas(
         )
     step0 = _step0_base(step0)
 
+    draw = None
     if backend.name == "fused":
         c = init_words.shape[1]
         k0c, k1c = _fused_key_cols(key, c)
@@ -469,18 +512,20 @@ def _run_pallas(
             )
     else:
 
-        def run_chunk(state, start, n):
-            flips, u = backend.chunk(key, step0 + start, n, state.shape, nbits)
+        def draw(start, n):
+            return backend.chunk(
+                key, step0 + start, n, init_words.shape, nbits
+            )
+
+        def run_chunk(state, start, n, flips, u):
             return mh_ops.mh_sample(
                 target.table, state, flips, u, nbits=nbits, block_c=block_c
             )
 
-    samples, acc, state = _drive_pallas_chunks(
+    return _drive_pallas_chunks(
         run_chunk, init_words.astype(jnp.uint32), n_steps, chunk, step0,
-        collect,
+        collect, draw, backend.name,
     )
-    logp = target.log_prob(state).astype(jnp.float32)
-    return samples, acc, state, logp
 
 
 def _gibbs_step(target, state, acc, u, parity):
@@ -491,11 +536,13 @@ def _gibbs_step(target, state, acc, u, parity):
     site's new value as u < sigmoid(logit), write it on the active
     checkerboard colour only.  There is no reject — ``acc`` counts sites
     whose value actually changed (the flip count)."""
-    logit = target.conditional_logit(state)
-    new = (u < jax.nn.sigmoid(logit)).astype(jnp.uint32)
-    active = target.update_mask(state.shape, parity)
-    nxt = jnp.where(active, new, state)
-    return nxt, acc + (nxt != state).astype(jnp.int32)
+    with jax.named_scope("gibbs.conditional"):
+        logit = target.conditional_logit(state)
+        new = (u < jax.nn.sigmoid(logit)).astype(jnp.uint32)
+    with jax.named_scope("gibbs.select"):
+        active = target.update_mask(state.shape, parity)
+        nxt = jnp.where(active, new, state)
+        return nxt, acc + (nxt != state).astype(jnp.int32)
 
 
 def _run_scan_gibbs(
@@ -506,9 +553,10 @@ def _run_scan_gibbs(
 
     def make_xs(start, n):
         # gibbs draws no proposal — the operand-lean u-only path
-        _, u = backend.chunk(key, start, n, shape, 1, need_flips=False)
-        idx = start + jnp.arange(n, dtype=jnp.int32)
-        return (u, idx)
+        with jax.named_scope("gibbs.draw"):
+            _, u = backend.chunk(key, start, n, shape, 1, need_flips=False)
+            idx = start + jnp.arange(n, dtype=jnp.int32)
+            return (u, idx)
 
     def step_fn(c, x):
         u_t, t = x
@@ -533,6 +581,7 @@ def _run_pallas_gibbs(
     step0 = _step0_base(step0)
     logit_fn, consts = _fused_gibbs_logit(target)
 
+    draw = None
     if backend.name == "fused":
         b = init_words.shape[0]
         k0b, k1b = _fused_key_cols(key, b)
@@ -544,17 +593,20 @@ def _run_pallas_gibbs(
             )
     else:
 
-        def run_chunk(state, start, n):
+        def draw(start, n):
             _, u = backend.chunk(
-                key, step0 + start, n, state.shape, 1, need_flips=False
+                key, step0 + start, n, init_words.shape, 1, need_flips=False
             )
+            return (u,)
+
+        def run_chunk(state, start, n, u):
             return gibbs_ops.gibbs_sweep(
                 state, u, logit_fn, parity0=(step0 + start) % 2, consts=consts
             )
 
     return _drive_pallas_chunks(
         run_chunk, init_words.astype(jnp.uint32), n_steps, chunk, step0,
-        collect,
+        collect, draw, backend.name,
     )
 
 
@@ -595,6 +647,7 @@ def _run_pallas_chains(
         b, c_chains * cc
     )
 
+    draw = None
     if backend.name == "fused":
         k0c, k1c = _fused_key_cols(keys, cc)  # chain-major: matches fold
 
@@ -605,17 +658,19 @@ def _run_pallas_chains(
             )
     else:
 
-        def run_chunk(state, start, n):
+        def draw(start, n):
             flips, u = jax.vmap(
                 lambda k: backend.chunk(k, step0 + start, n, (b, cc), nbits)
             )(keys)
+            return _chains_fold_mh(flips), _chains_fold_mh(u)
+
+        def run_chunk(state, start, n, flips, u):
             return mh_ops.mh_sample(
-                target.table, state, _chains_fold_mh(flips),
-                _chains_fold_mh(u), nbits=nbits, block_c=block_c,
+                target.table, state, flips, u, nbits=nbits, block_c=block_c,
             )
 
     samples, acc, state = _drive_pallas_chunks(
-        run_chunk, state0, n_steps, chunk, step0, collect
+        run_chunk, state0, n_steps, chunk, step0, collect, draw, backend.name
     )
 
     def unfold(x):  # (..., B, C*Cc) -> (C, ..., B, Cc)
@@ -655,6 +710,7 @@ def _run_pallas_gibbs_chains(
     c_chains, b, h, w = init.shape
     state0 = init.astype(jnp.uint32).reshape(c_chains * b, h, w)
 
+    draw = None
     if backend.name == "fused":
         k0b, k1b = _fused_key_cols(keys, b)  # chain-major: matches fold
 
@@ -665,22 +721,26 @@ def _run_pallas_gibbs_chains(
             )
     else:
 
-        def run_chunk(state, start, n):
+        def draw(start, n):
             u = jax.vmap(
                 lambda k: backend.chunk(
                     k, step0 + start, n, (b, h, w), 1, need_flips=False
                 )[1]
             )(keys)
-            u_fold = jnp.transpose(u, (1, 0, 2, 3, 4)).reshape(
-                n, c_chains * b, h, w
+            return (
+                jnp.transpose(u, (1, 0, 2, 3, 4)).reshape(
+                    n, c_chains * b, h, w
+                ),
             )
+
+        def run_chunk(state, start, n, u_fold):
             return gibbs_ops.gibbs_sweep(
                 state, u_fold, logit_fn, parity0=(step0 + start) % 2,
                 consts=consts,
             )
 
     samples, acc, state = _drive_pallas_chunks(
-        run_chunk, state0, n_steps, chunk, step0, collect
+        run_chunk, state0, n_steps, chunk, step0, collect, draw, backend.name
     )
 
     def unfold(x):  # (..., C*B, H, W) -> (C, ..., B, H, W)
@@ -689,6 +749,28 @@ def _run_pallas_gibbs_chains(
         return jnp.moveaxis(x, len(lead), 0)
 
     return unfold(samples), unfold(acc), unfold(state)
+
+
+def _gibbs_logp(target, words):
+    """Per-site conditional log-prob (pseudo-likelihood) of a Gibbs
+    state: its ``final_logp``."""
+    logit = target.conditional_logit(words)
+    return jnp.where(
+        words == 1, jax.nn.log_sigmoid(logit), jax.nn.log_sigmoid(-logit)
+    ).astype(jnp.float32)
+
+
+def _result(samples, acc, words, logp, n_steps: int, size: int):
+    """The ``EngineResult`` of a run over ``size`` sites."""
+    total = jnp.float32(n_steps) * jnp.float32(max(1, size))
+    return EngineResult(
+        samples=samples,
+        accept_count=acc,
+        acceptance_rate=jnp.sum(acc).astype(jnp.float32) / total,
+        final_words=words,
+        final_logp=logp,
+        n_steps=jnp.int32(n_steps),
+    )
 
 
 def _shard_over_chains(body, mesh, num_chains: int, n_out: int):
@@ -850,18 +932,18 @@ class MHEngine:
                     "init_logp needs scan execution — the pallas MH kernel "
                     "re-derives the table log-prob from the state words"
                 )
-            samples, acc, words, logp = _run_pallas(
+            samples, acc, words = _run_pallas(
                 *args, self.config.block_c, init_words, collect
             )
-        total = jnp.float32(n_steps) * jnp.float32(max(1, init_words.size))
-        return EngineResult(
-            samples=samples,
-            accept_count=acc,
-            acceptance_rate=jnp.sum(acc).astype(jnp.float32) / total,
-            final_words=words,
-            final_logp=logp,
-            n_steps=jnp.int32(n_steps),
-        )
+            logp = None
+        # the job's tail: the pallas kernel carries no log-prob, so it is
+        # re-derived from the final state
+        with _host_spans()("engine.finish"):
+            if logp is None:
+                logp = target.log_prob(words).astype(jnp.float32)
+            return _result(
+                samples, acc, words, logp, n_steps, init_words.size
+            )
 
     def _parse_collect(self, collect: str | None, step0) -> tuple[str, int]:
         """Resolve the run-level override against the config default and
@@ -903,19 +985,11 @@ class MHEngine:
             samples, acc, words = _run_scan_gibbs(*args, init_words, collect)
         else:
             samples, acc, words = _run_pallas_gibbs(*args, init_words, collect)
-        logit = target.conditional_logit(words)
-        logp = jnp.where(
-            words == 1, jax.nn.log_sigmoid(logit), jax.nn.log_sigmoid(-logit)
-        ).astype(jnp.float32)
-        total = jnp.float32(n_steps) * jnp.float32(max(1, init_words.size))
-        return EngineResult(
-            samples=samples,
-            accept_count=acc,
-            acceptance_rate=jnp.sum(acc).astype(jnp.float32) / total,
-            final_words=words,
-            final_logp=logp,
-            n_steps=jnp.int32(n_steps),
-        )
+        with _host_spans()("engine.finish"):
+            logp = _gibbs_logp(target, words)
+            return _result(
+                samples, acc, words, logp, n_steps, init_words.size
+            )
 
     def _run_chains(
         self, key, target, n_steps: int, init_words, mesh, base: int = 0,
@@ -968,12 +1042,7 @@ class MHEngine:
 
             body = _shard_over_chains(body, mesh, num_chains, 3)
             samples, acc, words = body(keys, init)
-            logit = target.conditional_logit(words)
-            logp = jnp.where(
-                words == 1,
-                jax.nn.log_sigmoid(logit),
-                jax.nn.log_sigmoid(-logit),
-            ).astype(jnp.float32)
+            logp = None
         else:
             execution = resolve_execution(cfg.execution, target)
             nbits = target.nbits
@@ -996,15 +1065,10 @@ class MHEngine:
 
             body = _shard_over_chains(body, mesh, num_chains, 4)
             samples, acc, words, logp = body(keys, init)
-        total = jnp.float32(n_steps) * jnp.float32(max(1, init.size))
-        return EngineResult(
-            samples=samples,
-            accept_count=acc,
-            acceptance_rate=jnp.sum(acc).astype(jnp.float32) / total,
-            final_words=words,
-            final_logp=logp,
-            n_steps=jnp.int32(n_steps),
-        )
+        with _host_spans()("engine.finish"):
+            if logp is None:
+                logp = _gibbs_logp(target, words)
+            return _result(samples, acc, words, logp, n_steps, init.size)
 
     def sample_tokens(
         self,

@@ -42,6 +42,7 @@ from repro import telemetry
 from repro.samplers.engine import (
     EngineResult,
     MHEngine,
+    _host_side,
     parse_collect,
     resolve_execution,
 )
@@ -53,14 +54,6 @@ def fingerprint_digest(fingerprint: dict) -> str:
     checkpoints to runs without dumping the whole key."""
     blob = json.dumps(fingerprint, sort_keys=True).encode()
     return hashlib.sha256(blob).hexdigest()[:12]
-
-
-def _host_side() -> bool:
-    """True outside any jax trace — telemetry spans only make sense (and
-    only read python ints safely) at the host level; traced re-entries
-    (the serving tier's vmapped advance, tempering's jitted segments)
-    skip instrumentation entirely."""
-    return jax.core.trace_ctx.is_top_level()
 
 
 def carries_logp(engine: "MHEngine", target) -> bool:

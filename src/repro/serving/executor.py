@@ -397,29 +397,35 @@ class PackedExecutor:
         except StopIteration:
             raise RuntimeError("no free slot — check has_free_slot()") from None
         member = self.member_for(getattr(req, "workload", None))
-        init, k_run, n_steps = member.request_init(req)
-        init = jnp.asarray(init)
-        if tuple(init.shape) != member.state_shape:
-            raise ValueError(
-                f"request init shape {tuple(init.shape)} != member state "
-                f"shape {member.state_shape} — one member serves one "
-                f"workload group"
-            )
-        mode, k = parse_collect(req.collect)
-        words0 = init.astype(jnp.uint32)
-        if self.execution == "scan":
-            flat = jnp.pad(
-                words0.reshape(-1), (0, self.n_pad - member.size)
-            )
-            self.words = self.words.at[slot].set(flat)
-            if member.carry_logp:
-                lp0 = member.target.log_prob(words0).astype(jnp.float32)
-                self.logp = self.logp.at[slot].set(
-                    jnp.pad(lp0.reshape(-1), (0, self.n_pad - member.size))
+        with telemetry.span(
+            "serving.admit", workload=getattr(req, "workload", None),
+            slot=slot,
+        ):
+            init, k_run, n_steps = member.request_init(req)
+            init = jnp.asarray(init)
+            if tuple(init.shape) != member.state_shape:
+                raise ValueError(
+                    f"request init shape {tuple(init.shape)} != member "
+                    f"state shape {member.state_shape} — one member "
+                    f"serves one workload group"
                 )
-        else:
-            self.words = self.words.at[slot].set(words0)
-        self._keys[slot] = jnp.asarray(k_run, jnp.uint32)
+            mode, k = parse_collect(req.collect)
+            words0 = init.astype(jnp.uint32)
+            if self.execution == "scan":
+                flat = jnp.pad(
+                    words0.reshape(-1), (0, self.n_pad - member.size)
+                )
+                self.words = self.words.at[slot].set(flat)
+                if member.carry_logp:
+                    lp0 = member.target.log_prob(words0).astype(jnp.float32)
+                    self.logp = self.logp.at[slot].set(
+                        jnp.pad(
+                            lp0.reshape(-1), (0, self.n_pad - member.size)
+                        )
+                    )
+            else:
+                self.words = self.words.at[slot].set(words0)
+            self._keys[slot] = jnp.asarray(k_run, jnp.uint32)
         self._slots[slot] = _Slot(
             req=req, member=member, remaining=int(n_steps), mode=mode,
             thin_k=k,
@@ -493,19 +499,20 @@ class PackedExecutor:
         """One vmapped class program over all slots: flat donated
         (words, logp) carry, traced per-slot ``step0``, per-slot member
         dispatch (dispatch.make_class_advance_fn)."""
-        collect, step0s, keys = self._segment_inputs(active)
-        tidx = jnp.asarray(
-            [s.member.index if s else 0 for s in self._slots], jnp.int32
-        )
+        with telemetry.span("serving.inputs"):
+            collect, step0s, keys = self._segment_inputs(active)
+            tidx = jnp.asarray(
+                [s.member.index if s else 0 for s in self._slots], jnp.int32
+            )
         old_words, old_logp = self.words, self.logp
-        before = self._advance._cache_size()
-        samples, words, logp, acc = self._advance(
-            old_words, old_logp, keys, step0s, tidx, seg=seg, collect=collect
-        )
-        self._count_compiles(before)
+        with telemetry.span("serving.dispatch"):
+            before = self._advance._cache_size()
+            samples, words, logp, acc = self._advance(
+                old_words, old_logp, keys, step0s, tidx, seg=seg,
+                collect=collect,
+            )
+            self._count_compiles(before)
         self.words, self.logp = words, logp
-        # the donated carries are dead from here on — make stale reads loud
-        dispatch.poison_donated(old_words, old_logp)
 
         def rows(i, m):
             return samples[i][:, :m.size].reshape(-1, *m.state_shape)
@@ -513,33 +520,40 @@ class PackedExecutor:
         def unflat(buf, i, m):
             return buf[i, :m.size].reshape(m.state_shape)
 
-        return self._bookkeep(
-            active, seg, collect, rows,
-            lambda i, m: unflat(acc, i, m),
-            lambda i, m: unflat(words, i, m),
-            lambda i, m: unflat(logp, i, m),
-        )
+        with telemetry.span("serving.bookkeep"):
+            # the donated carries are dead from here on — make stale
+            # reads loud
+            dispatch.poison_donated(old_words, old_logp)
+            return self._bookkeep(
+                active, seg, collect, rows,
+                lambda i, m: unflat(acc, i, m),
+                lambda i, m: unflat(words, i, m),
+                lambda i, m: unflat(logp, i, m),
+            )
 
     def _advance_pallas(self, active, seg: int) -> list:
         """One batched fused-kernel grid over all slots: shaped donated
         words carry, per-slot key words and operand ``step0``
         (dispatch.make_pallas_advance_fn).  No per-slot fallback."""
-        collect, step0s, keys = self._segment_inputs(active)
+        with telemetry.span("serving.inputs"):
+            collect, step0s, keys = self._segment_inputs(active)
         old_words = self.words
-        before = self._advance._cache_size()
-        samples, words, logp, acc = self._advance(
-            old_words, keys, step0s, seg=seg, collect=collect
-        )
-        self._count_compiles(before)
+        with telemetry.span("serving.dispatch"):
+            before = self._advance._cache_size()
+            samples, words, logp, acc = self._advance(
+                old_words, keys, step0s, seg=seg, collect=collect
+            )
+            self._count_compiles(before)
         self.words = words
-        dispatch.poison_donated(old_words)
-        return self._bookkeep(
-            active, seg, collect,
-            lambda i, m: samples[i],
-            lambda i, m: acc[i],
-            lambda i, m: words[i],
-            lambda i, m: logp[i],
-        )
+        with telemetry.span("serving.bookkeep"):
+            dispatch.poison_donated(old_words)
+            return self._bookkeep(
+                active, seg, collect,
+                lambda i, m: samples[i],
+                lambda i, m: acc[i],
+                lambda i, m: words[i],
+                lambda i, m: logp[i],
+            )
 
     def _bookkeep(
         self, active, seg, collect, rows_of, acc_of, words_of, logp_of

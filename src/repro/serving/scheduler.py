@@ -312,7 +312,10 @@ class Scheduler:
             gap = nxt - self.clock()
             if gap > 0:
                 if realtime:
-                    time.sleep(min(gap, 0.05))
+                    # no slot busy, next arrival not yet due: the
+                    # traffic's idle, not the host's
+                    with telemetry.span("serving.idle"):
+                        time.sleep(min(gap, 0.05))
                 else:
                     self._skip += gap
         self.drain()

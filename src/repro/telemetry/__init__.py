@@ -45,6 +45,7 @@ from repro.telemetry.tracing import (
     enabled,
     instant,
     log,
+    null_span,
     span,
     validate_event,
     validate_jsonl,
@@ -60,6 +61,7 @@ __all__ = [
     "disable",
     "enabled",
     "span",
+    "null_span",
     "instant",
     "log",
     "clock",

@@ -16,13 +16,22 @@ drains through two exporters:
 
 Clock discipline: every event timestamps against ONE ``perf_counter``
 epoch captured when the tracer is created/reset (``ts_us`` = µs since
-epoch, float).  Spans measure *host* wall time between dispatches — JAX
+epoch, float).  While the process tracer is switched on by ``enable()``,
+each span also enters a ``jax.profiler.TraceAnnotation`` of the same
+name carrying its metadata, so a ``jax.profiler`` trace holds every
+program span on its host plane, on the device trace's own clock.
+Spans measure *host* wall time between dispatches — JAX
 dispatch is asynchronous, so a span around an un-blocked device call
 measures dispatch cost, not device time; instrumentation sites that want
 device time block first (the bench harness) or accept dispatch semantics
 (the serving segment spans, where the donation boundary forces the sync
 anyway).  Events carry a process-unique ``seq`` so equal-timestamp
 events keep their emission order.
+
+Recompiles: while the process tracer is on, a ``jax.monitoring``
+listener records each backend compile as a ``jax.compile`` span that
+ends when the listener hears of it, at the depth of the spans open on
+that thread, so a compile nests under the span that triggered it.
 
 Overhead contract: telemetry is OFF by default and the disabled path is
 one module-attribute check returning a shared no-op context manager —
@@ -118,10 +127,17 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+def null_span(name: str = "", **meta):
+    """The disabled path as a callable: the shared no-op span.  A site
+    that decides once whether to trace a loop binds ``span`` to this or
+    to ``telemetry.span``."""
+    return _NULL_SPAN
+
+
 class _Span:
     """One live span: records on exit so the buffer sees complete events."""
 
-    __slots__ = ("_tracer", "_name", "_meta", "_t0", "_depth")
+    __slots__ = ("_tracer", "_name", "_meta", "_t0", "_depth", "_note")
 
     def __init__(self, tracer: "Tracer", name: str, meta: dict):
         self._tracer = tracer
@@ -135,12 +151,20 @@ class _Span:
         return self
 
     def __enter__(self):
+        annotation = self._tracer.annotation
+        if annotation is None:
+            self._note = None
+        else:
+            self._note = annotation(self._name, **_clean_meta(self._meta))
+            self._note.__enter__()
         self._depth = self._tracer._push()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        if self._note is not None:
+            self._note.__exit__(None, None, None)
         self._tracer._pop()
         self._tracer._record(
             "span", self._name, self._t0, t1 - self._t0, self._depth,
@@ -164,6 +188,9 @@ class Tracer:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self.capacity = int(capacity)
         self.enabled = False
+        # span mirror on the profiler's clock (jax.profiler.TraceAnnotation),
+        # set by enable() for the process tracer; None records spans only
+        self.annotation = None
         self.dropped = 0
         self._events: deque[TraceEvent] = deque()
         self._lock = threading.Lock()
@@ -231,6 +258,18 @@ class Tracer:
         if not self.enabled:
             return _NULL_SPAN
         return _Span(self, name, meta)
+
+    def ended(self, name: str, dur_s: float, **meta) -> None:
+        """A span that ends now and lasted ``dur_s`` seconds, recorded
+        at the depth of the spans open on this thread (so it nests under
+        them) — for sections timed by someone else, such as compiles."""
+        if not self.enabled:
+            return
+        t1 = time.perf_counter()
+        self._record(
+            "span", name, t1 - dur_s, dur_s,
+            getattr(self._depths, "d", 0), meta,
+        )
 
     def instant(self, name: str, **meta) -> None:
         """A point event (zero duration)."""
@@ -327,15 +366,35 @@ class Tracer:
 TRACER = Tracer()
 
 
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_listener_registered = False
+
+
+def _on_compile(event: str, secs: float, **_kw) -> None:
+    if event == _COMPILE_EVENT:
+        TRACER.ended("jax.compile", secs)
+
+
 def enable(capacity: int | None = None) -> Tracer:
-    """Reset and switch on the default tracer."""
+    """Reset and switch on the default tracer: spans are mirrored as
+    ``jax.profiler.TraceAnnotation``s and backend compiles recorded as
+    ``jax.compile`` spans (jax is imported here, not at module import)."""
+    global _compile_listener_registered
+    import jax.monitoring
+    import jax.profiler
+
     TRACER.reset(capacity=capacity)
+    TRACER.annotation = jax.profiler.TraceAnnotation
+    if not _compile_listener_registered:
+        jax.monitoring.register_event_duration_secs_listener(_on_compile)
+        _compile_listener_registered = True
     TRACER.enabled = True
     return TRACER
 
 
 def disable() -> None:
     TRACER.enabled = False
+    TRACER.annotation = None
 
 
 def enabled() -> bool:

@@ -351,14 +351,36 @@ class TestHealth:
 # --------------------------------------------------------------------------
 
 
+def _children(events, parent):
+    """Spans one level under ``parent`` on its thread, inside its time."""
+    end = parent.ts_us + parent.dur_us
+    return [
+        e for e in events
+        if e.kind == "span" and e.tid == parent.tid
+        and e.depth == parent.depth + 1
+        and parent.ts_us <= e.ts_us and e.ts_us + e.dur_us <= end
+    ]
+
+
 class TestInstrumentation:
-    @pytest.mark.parametrize("update", ["mh", "gibbs"])
-    def test_submit_bit_parity_tracing_on_vs_off(self, update):
+    @pytest.mark.parametrize(
+        "update,execution",
+        [
+            pytest.param("mh", "scan", id="mh"),
+            pytest.param("gibbs", "scan", id="gibbs"),
+            pytest.param("mh", "pallas", id="mh-pallas"),
+            pytest.param("gibbs", "pallas", id="gibbs-pallas"),
+        ],
+    )
+    def test_submit_bit_parity_tracing_on_vs_off(self, update, execution):
         """The overhead contract's numerical half: tracing must never
-        touch the sampled stream."""
+        touch the sampled stream, on the scan executor or the eager
+        Pallas chunk loop."""
         target, init = _gibbs_setup() if update == "gibbs" else _mh_setup()
         engine = samplers.MHEngine(
-            samplers.EngineConfig(update=update, chunk_steps=8)
+            samplers.EngineConfig(
+                update=update, chunk_steps=8, execution=execution
+            )
         )
         plan = samplers.RunPlan(
             target=target, n_steps=20, init_words=init, seed=5
@@ -429,6 +451,86 @@ class TestInstrumentation:
         run(init)
         assert _host_side() and inside == [False]
         assert not [e for e in tr.events() if e.name == "engine.submit"]
+
+    @pytest.mark.parametrize(
+        "update,randomness",
+        [("mh", "cim"), ("mh", "fused"), ("gibbs", "host"),
+         ("gibbs", "fused")],
+    )
+    def test_pallas_chunk_loop_spans(self, update, randomness):
+        """The eager Pallas chunk loop records one ``engine.chunk`` per
+        chunk under ``engine.submit``, each holding one ``engine.emit``
+        and, where operands are drawn on the host, one
+        ``randomness.draw``; one ``engine.finish`` follows the loop."""
+        target, init = _gibbs_setup() if update == "gibbs" else _mh_setup()
+        engine = samplers.MHEngine(
+            samplers.EngineConfig(
+                update=update, randomness=randomness, execution="pallas",
+                chunk_steps=8,
+            )
+        )
+        plan = samplers.RunPlan(
+            target=target, n_steps=20, init_words=init, seed=3
+        )
+        tr = telemetry.enable()
+        engine.submit(plan)
+        evs = tr.events()
+        (submit,) = [e for e in evs if e.name == "engine.submit"]
+        chunks = [e for e in evs if e.name == "engine.chunk"]
+        assert len(chunks) == -(-20 // 8)
+        assert chunks == [
+            e for e in _children(evs, submit) if e.name == "engine.chunk"
+        ]
+        assert [(e.meta["start"], e.meta["n"]) for e in chunks] == [
+            (0, 8), (8, 8), (16, 4)
+        ]
+        (finish,) = [
+            e for e in _children(evs, submit) if e.name == "engine.finish"
+        ]
+        assert finish.ts_us >= chunks[-1].ts_us + chunks[-1].dur_us
+        draws = [e for e in evs if e.name == "randomness.draw"]
+        for c in chunks:
+            kids = sorted(
+                e.name for e in _children(evs, c) if e.name != "jax.compile"
+            )
+            if randomness == "fused":
+                assert kids == ["engine.emit"]
+            else:
+                assert kids == ["engine.emit", "randomness.draw"]
+        if randomness == "fused":
+            assert draws == []
+        else:
+            assert len(draws) == len(chunks)
+            assert {e.meta["backend"] for e in draws} == {randomness}
+            assert [e.meta["n"] for e in draws] == [8, 8, 4]
+
+    def test_chunk_spans_absent_when_traced(self):
+        """The chunk loop staged under a trace (the compiled submit, or
+        a caller's ``jax.jit``) records none of its spans."""
+        target, init = _mh_setup()
+        engine = samplers.MHEngine(
+            samplers.EngineConfig(
+                randomness="cim", execution="pallas", chunk_steps=8
+            )
+        )
+        plan = samplers.RunPlan(
+            target=target, n_steps=12, init_words=init, seed=2
+        )
+
+        @jax.jit
+        def run(words):
+            return engine.run(
+                jax.random.PRNGKey(2), target, 12, words
+            ).final_words
+
+        tr = telemetry.enable()
+        engine.submit(plan, compiled=True)
+        run(init)
+        names = {e.name for e in tr.events()}
+        assert "engine.submit" in names
+        assert not names & {
+            "engine.chunk", "engine.emit", "randomness.draw", "engine.finish"
+        }
 
     def test_run_resumable_emits_segment_logs(self, tmp_path):
         target, init = _mh_setup()
@@ -503,6 +605,90 @@ class TestInstrumentation:
         ) == 2
         assert reg.counter("serving_requests_retired_total").value() == 2
 
+    def test_serving_loop_spans(self):
+        """One ``serving.admit`` per admitted request; the segment's
+        inputs, dispatch and bookkeeping nest one level under
+        ``serving.segment``; a real-time wait for an arrival with no slot
+        busy is ``serving.idle``."""
+        from repro.serving import Scheduler, ServeRequest
+
+        tr = telemetry.enable()
+        sched = Scheduler(n_slots=2, smoke=True, workload_kwargs={})
+        reqs = [
+            ServeRequest(rid=i, workload="gmm", n_steps=8, seed=i,
+                         t_arrive=0.05)
+            for i in range(3)
+        ]
+        sched.serve(reqs, realtime=True)
+        evs = tr.events()
+        admits = [e for e in evs if e.name == "serving.admit"]
+        assert len(admits) == 3
+        assert {e.meta["workload"] for e in admits} == {"gmm"}
+        assert sorted(e.meta["slot"] for e in admits) == [0, 0, 1]
+        segments = [e for e in evs if e.name == "serving.segment"]
+        assert segments
+        for seg in segments:
+            kids = [
+                e.name for e in _children(evs, seg)
+                if e.name != "jax.compile"
+            ]
+            assert kids == [
+                "serving.inputs", "serving.dispatch", "serving.bookkeep"
+            ]
+        idle = [e for e in evs if e.name == "serving.idle"]
+        assert idle and all(e.depth == 0 for e in idle)
+
+    def test_spans_land_on_the_profiler_host_plane(self, tmp_path):
+        """While enabled, every span is mirrored as a profiler
+        annotation: a ``jax.profiler`` trace holds it by name on a
+        ``/host:`` plane."""
+        import glob
+
+        tr = telemetry.enable()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with tr.span("test.mirrored", n=3):
+                jnp.ones(4).block_until_ready()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(
+            str(tmp_path / "**" / "*.xplane.pb"), recursive=True
+        )
+        found = [
+            ev
+            for plane in jax.profiler.ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines
+            for ev in line.events
+            if ev.name == "test.mirrored"
+        ]
+        assert len(found) == 1 and found[0].duration_ns > 0
+
+    def test_compile_recorded_under_the_open_span(self):
+        """A backend compile while tracing is a ``jax.compile`` span
+        nested in the span that triggered it."""
+        from jax.experimental.compilation_cache import compilation_cache
+
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            x = jnp.arange(5.0)
+            fresh = jax.jit(lambda v: v * 3.25 - 1.0)
+            tr = telemetry.enable()
+            with tr.span("outer"):
+                fresh(x).block_until_ready()
+            fresh(x).block_until_ready()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            compilation_cache.reset_cache()
+        evs = tr.events()
+        (outer,) = [e for e in evs if e.name == "outer"]
+        compiles = [e for e in evs if e.name == "jax.compile"]
+        assert len(compiles) == 1
+        assert compiles == _children(evs, outer)
+        assert compiles[0].dur_us > 0
+
     def test_tempering_emits_swap_spans(self):
         from repro import tempering
 
@@ -565,3 +751,25 @@ class TestMonitorCLI:
         rows = monitor_cli.summarize_events(events)
         assert rows[0]["span"] == "b" and rows[0]["share"] == 0.6
         assert rows[1]["span"] == "a" and rows[1]["count"] == 2
+        # nested: a child's time is its own, not its parent's as well
+        nested = [
+            {"kind": "span", "name": "submit", "ts_us": 0.0,
+             "dur_us": 100.0, "tid": 0, "depth": 0},
+            {"kind": "span", "name": "chunk", "ts_us": 10.0,
+             "dur_us": 40.0, "tid": 0, "depth": 1},
+            {"kind": "span", "name": "emit", "ts_us": 30.0,
+             "dur_us": 10.0, "tid": 0, "depth": 2},
+            {"kind": "span", "name": "chunk", "ts_us": 55.0,
+             "dur_us": 40.0, "tid": 0, "depth": 1},
+            # another thread's top-level span at the same time
+            {"kind": "span", "name": "other", "ts_us": 20.0,
+             "dur_us": 100.0, "tid": 1, "depth": 0},
+        ]
+        rows = {r["span"]: r for r in monitor_cli.summarize_events(nested)}
+        assert rows["chunk"]["total_ms"] == 0.08
+        assert rows["submit"]["total_ms"] == 0.1
+        assert rows["submit"]["share"] == 0.1    # 20 of 200 us
+        assert rows["chunk"]["share"] == 0.35    # 70 of 200 us
+        assert rows["emit"]["share"] == 0.05
+        assert rows["other"]["share"] == 0.5
+        assert sum(r["share"] for r in rows.values()) == pytest.approx(1.0)
