@@ -60,6 +60,7 @@ from repro.samplers.randomness import (
     RandomnessBackend,
     chain_key,
     chain_keys,
+    draw_cache_size,
     make_randomness_backend,
 )
 from repro.samplers.targets import logits_target
@@ -385,7 +386,7 @@ def _chunk_writer(ndim: int):
 
 def _drive_pallas_chunks(
     run_chunk, init_state, n_steps, chunk, step0, collect, draw=None,
-    randomness=None,
+    randomness=None, draw_cache=None,
 ):
     """THE fused-executor chunk scheduler — the python chunk loop all four
     pallas executors share.
@@ -413,8 +414,13 @@ def _drive_pallas_chunks(
     records one ``engine.chunk`` span per chunk, holding a
     ``randomness.draw`` span around the operand draw and an
     ``engine.emit`` span around the kept-row write and the state/count
-    glue; the caller's ``engine.finish`` covers the job's tail.  Under a
-    trace the loop is staged, not run, and records nothing.
+    glue; the caller's ``engine.finish`` covers the job's tail.  Where
+    ``draw`` calls the compiled draw directly (the solo executors),
+    ``draw_cache`` reads its cache size, and each ``randomness.draw``
+    span carries ``jit_cache="miss"`` if the draw compiled, else
+    ``"hit"``; the chains executors call it under ``vmap``, whose
+    batched program JAX caches apart, so they pass none.  Under a trace
+    the loop is staged, not run, and records nothing.
     """
     mode, k = collect
     chunk = _effective_chunk(n_steps, chunk, k if mode == "thin" else None)
@@ -428,6 +434,8 @@ def _drive_pallas_chunks(
         n_keep = 0
     traced = isinstance(state, jax.core.Tracer)
     span = _host_spans(traced)
+    if span is not telemetry.span:
+        draw_cache = None
     out = jnp.zeros((n_keep, *state.shape), jnp.uint32)
     zeros = (0,) * state.ndim
     pos = 0
@@ -445,8 +453,14 @@ def _drive_pallas_chunks(
         with span("engine.chunk", start=start, n=n):
             operands = ()
             if draw is not None:
-                with span("randomness.draw", backend=randomness, n=n):
+                with span("randomness.draw", backend=randomness, n=n) as sp:
+                    before = draw_cache() if draw_cache else 0
                     operands = draw(start, n)
+                    if draw_cache:
+                        sp.set(
+                            jit_cache="miss" if draw_cache() > before
+                            else "hit"
+                        )
             samples, a = run_chunk(state, start, n, *operands)
             with span("engine.emit"):
                 state = samples[-1]
@@ -524,7 +538,7 @@ def _run_pallas(
 
     return _drive_pallas_chunks(
         run_chunk, init_words.astype(jnp.uint32), n_steps, chunk, step0,
-        collect, draw, backend.name,
+        collect, draw, backend.name, draw_cache_size,
     )
 
 
@@ -606,7 +620,7 @@ def _run_pallas_gibbs(
 
     return _drive_pallas_chunks(
         run_chunk, init_words.astype(jnp.uint32), n_steps, chunk, step0,
-        collect, draw, backend.name,
+        collect, draw, backend.name, draw_cache_size,
     )
 
 

@@ -31,6 +31,13 @@ resulting stream is *bit-identical* to the monolithic (K, B, C)
 materialisation.  Long chains are therefore memory-bounded by the chunk
 size, not the chain length.
 
+One program per chunk (DESIGN.md §Randomness): every backend's
+``chunk`` runs its ``draw`` body as one compiled program per ``(backend,
+n_steps, shape, nbits, need_flips)``, with the key and ``start`` traced.
+An eager caller (the Pallas chunk loop) pays one dispatch a chunk
+instead of one per op; under a trace the body is inlined, so staged
+programs are unchanged.  The stream is the body's, bit for bit.
+
 Operand-lean mode (DESIGN.md §Collection): consumers that never read the
 flip words — the Gibbs update rule draws no proposal, and the tempering
 swap test needs only a uniform — pass ``need_flips=False`` and the
@@ -44,6 +51,7 @@ flip stream was ever consumed (asserted in tests/test_collection.py).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Protocol, runtime_checkable
 
 import jax
@@ -100,15 +108,43 @@ class RandomnessBackend(Protocol):
         ...
 
 
+@functools.partial(jax.jit, static_argnums=(0, 3, 4, 5, 6), inline=True)
+def _chunk_program(backend, key, start, n_steps, shape, nbits, need_flips):
+    """One compiled program per ``(backend, n_steps, shape, nbits,
+    need_flips)``: the key and ``start`` are traced, so every chunk of a
+    run, and every later run, reuses it.  Called eagerly it is one
+    dispatch; under a trace ``inline=True`` splices the body into the
+    caller's jaxpr, so staged programs are the ones the body alone
+    would give."""
+    return backend.draw(key, start, n_steps, shape, nbits, need_flips)
+
+
+def draw_cache_size() -> int:
+    """Entries in the compiled draw's cache: grows iff a call compiled."""
+    return _chunk_program._cache_size()
+
+
+class _CompiledChunk:
+    """The shared ``chunk`` of the three backends: their ``draw`` body,
+    compiled once per static signature (``_chunk_program``).  Backends
+    are frozen dataclasses, so equal backends share the cache."""
+
+    def chunk(self, key, start, n_steps, shape, nbits, need_flips=True):
+        return _chunk_program(
+            self, key, start, int(n_steps), tuple(shape), int(nbits),
+            bool(need_flips),
+        )
+
+
 @dataclasses.dataclass(frozen=True)
-class HostRandomness:
+class HostRandomness(_CompiledChunk):
     """Ideal software randomness — the baseline the CIM pipeline replaces."""
 
     p_bfr: float = 0.45
 
     name = "host"
 
-    def chunk(self, key, start, n_steps, shape, nbits, need_flips=True):
+    def draw(self, key, start, n_steps, shape, nbits, need_flips):
         def one(k):
             k_flip, k_u = jax.random.split(k)
             u = jax.random.uniform(k_u, shape, jnp.float32)
@@ -128,7 +164,7 @@ class HostRandomness:
 
 
 @dataclasses.dataclass(frozen=True)
-class CIMRandomness:
+class CIMRandomness(_CompiledChunk):
     """Paper-faithful randomness: pseudo-read bit-planes + MSXOR uniforms."""
 
     p_bfr: float = 0.45            # proposal pseudo-read flip rate
@@ -138,7 +174,7 @@ class CIMRandomness:
 
     name = "cim"
 
-    def chunk(self, key, start, n_steps, shape, nbits, need_flips=True):
+    def draw(self, key, start, n_steps, shape, nbits, need_flips):
         def one(k):
             k_flip, k_u = jax.random.split(k)
             u = uniform_rng.uniform(
@@ -156,7 +192,7 @@ class CIMRandomness:
 
 
 @dataclasses.dataclass(frozen=True)
-class FusedRandomness:
+class FusedRandomness(_CompiledChunk):
     """In-kernel counter RNG — the scan-side reference stream.
 
     The stream contract (kernels/rng): operand for (chain, step t, site
@@ -173,7 +209,7 @@ class FusedRandomness:
 
     name = "fused"
 
-    def chunk(self, key, start, n_steps, shape, nbits, need_flips=True):
+    def draw(self, key, start, n_steps, shape, nbits, need_flips):
         k0, k1 = rng.key_words(key)
         site = rng.site_index(shape)
         p_u32 = rng.threshold_u32(self.p_bfr)

@@ -20,6 +20,7 @@ import numpy as np
 import pytest
 
 from repro import samplers, workloads
+from repro.samplers.randomness import draw_cache_size
 from repro.workloads.ising import IsingModel
 
 
@@ -297,4 +298,108 @@ class TestWorkloadAndTemperingWiring:
         for r in (r_thin, r_last):
             np.testing.assert_array_equal(
                 np.asarray(r.final_words), np.asarray(r_all.final_words)
+            )
+
+
+def _per_step_draw(name, key, start, n_steps, shape, nbits, need_flips):
+    """The (flips, u) stream rebuilt step by step with no jit: the
+    per-step ``fold_in(key, t)``, the ``(k_flip, k_u)`` split, then the
+    backend's own draw of each operand."""
+    from repro.core import bitcell, uniform_rng
+
+    flips, us = [], []
+    with jax.disable_jit():
+        for t in range(start, start + n_steps):
+            k_flip, k_u = jax.random.split(jax.random.fold_in(key, t))
+            if name == "host":
+                us.append(jax.random.uniform(k_u, shape, jnp.float32))
+                planes = jax.random.bernoulli(k_flip, 0.45, (*shape, nbits))
+                flips.append(
+                    np.sum(
+                        np.asarray(planes, np.uint32)
+                        << np.arange(nbits, dtype=np.uint32),
+                        axis=-1, dtype=np.uint32,
+                    )
+                )
+            else:
+                us.append(uniform_rng.uniform(k_u, shape, 0.45, 16, 3))
+                flips.append(
+                    bitcell.raw_random_words(k_flip, 0.45, shape, nbits)
+                )
+    u = np.stack([np.asarray(x) for x in us])
+    if not need_flips:
+        return None, u
+    return np.stack([np.asarray(x) for x in flips]), u
+
+
+class TestCompiledDraw:
+    """``RandomnessBackend.chunk`` is one compiled program per ``(backend,
+    n_steps, shape, nbits, need_flips)`` and draws the same stream as
+    the per-step body, word for word."""
+
+    @pytest.mark.parametrize("need_flips", [True, False])
+    @pytest.mark.parametrize("name", ["host", "cim"])
+    @pytest.mark.parametrize("start_kind", ["python", "traced"])
+    def test_chunk_equals_per_step_draw(self, name, need_flips, start_kind):
+        backend = samplers.make_randomness_backend(name, p_bfr=0.45)
+        key = jax.random.PRNGKey(41)
+        shape, nbits, start, n = (2, 5), 6, 9, 4
+        if start_kind == "python":
+            flips, u = backend.chunk(key, start, n, shape, nbits, need_flips)
+        else:
+            flips, u = jax.jit(
+                lambda s: backend.chunk(key, s, n, shape, nbits, need_flips)
+            )(jnp.int32(start))
+        ref_flips, ref_u = _per_step_draw(
+            name, key, start, n, shape, nbits, need_flips
+        )
+        assert u.dtype == jnp.float32 and u.shape == (n, *shape)
+        np.testing.assert_array_equal(np.asarray(u), ref_u)
+        if need_flips:
+            assert flips.dtype == jnp.uint32
+            np.testing.assert_array_equal(np.asarray(flips), ref_flips)
+        else:
+            assert flips is None
+
+    @pytest.mark.parametrize(
+        "name,width", [("host", 11), ("cim", 12), ("fused", 13)]
+    )
+    def test_chunks_at_other_starts_share_one_program(self, name, width):
+        """The key and ``start`` are traced: two chunks of equal length
+        at different starts compile once.  (``width`` keeps each case's
+        signature new to the process-wide cache.)"""
+        backend = samplers.make_randomness_backend(name, p_bfr=0.45)
+        key = jax.random.PRNGKey(43)
+        before = draw_cache_size()
+        a = backend.chunk(key, 0, 3, (1, width), 4)
+        b = backend.chunk(jax.random.PRNGKey(44), 96, 3, (1, width), 4)
+        assert draw_cache_size() == before + 1
+        assert not np.array_equal(np.asarray(a[1]), np.asarray(b[1]))
+
+    @pytest.mark.parametrize("randomness", ["host", "cim"])
+    @pytest.mark.parametrize("update", ["mh", "gibbs"])
+    def test_pallas_chunked_equals_monolithic(self, update, randomness):
+        """The eager Pallas executors draw each chunk through the
+        compiled program; chunked and monolithic runs agree bit for bit
+        (interpret mode off the chip)."""
+        if update == "gibbs":
+            target, init = _gibbs_case()
+        else:
+            target, init = _mh_case(chains=8)
+        key = jax.random.PRNGKey(47)
+
+        def run(chunk):
+            engine = samplers.MHEngine(
+                samplers.EngineConfig(
+                    update=update, randomness=randomness,
+                    execution="pallas", chunk_steps=chunk,
+                )
+            )
+            return engine.run(key, target, 12, init, collect="all")
+
+        chunked, mono = run(5), run(64)
+        for field in ("samples", "accept_count", "final_words"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(chunked, field)),
+                np.asarray(getattr(mono, field)),
             )

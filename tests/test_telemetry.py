@@ -504,6 +504,41 @@ class TestInstrumentation:
             assert {e.meta["backend"] for e in draws} == {randomness}
             assert [e.meta["n"] for e in draws] == [8, 8, 4]
 
+    def test_draw_spans_record_jit_cache_verdict(self):
+        """Each eager cim ``randomness.draw`` span says whether the
+        compiled draw compiled: ``miss`` on the first chunk, ``hit`` on
+        every later one (equal lengths, later starts, a second job); the
+        same run staged inside ``jax.jit`` records no draw span."""
+        from repro.samplers.randomness import _chunk_program
+
+        target, init = _mh_setup()
+        engine = samplers.MHEngine(
+            samplers.EngineConfig(
+                randomness="cim", execution="pallas", chunk_steps=8
+            )
+        )
+        plan = samplers.RunPlan(
+            target=target, n_steps=24, init_words=init, seed=5
+        )
+        _chunk_program.clear_cache()  # the first chunk must compile
+        tr = telemetry.enable()
+        engine.submit(plan)
+        engine.submit(plan)
+
+        def verdicts():
+            return [
+                e.meta.get("jit_cache")
+                for e in tr.events() if e.name == "randomness.draw"
+            ]
+
+        assert verdicts() == ["miss"] + ["hit"] * 5
+        jax.jit(
+            lambda w: engine.run(
+                jax.random.PRNGKey(5), target, 24, w
+            ).final_words
+        )(init)
+        assert len(verdicts()) == 6
+
     def test_chunk_spans_absent_when_traced(self):
         """The chunk loop staged under a trace (the compiled submit, or
         a caller's ``jax.jit``) records none of its spans."""
