@@ -10,29 +10,36 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import samplers
 from repro.core import token_sampler
 
 
-def _tv_for(cfg, logits, ref, n_runs=300, seed=0):
-    sample = jax.jit(
-        lambda k: token_sampler.sample_tokens(k, logits, cfg).tokens
+def _sampler(cfg, logits):
+    """One decode step of ``cfg`` over ``logits`` through
+    ``MHEngine.sample_tokens``: (tokens, EngineResult) of a key."""
+    engine = samplers.MHEngine(cfg.engine_config())
+    return lambda k: engine.sample_tokens(
+        k, logits, n_steps=cfg.n_steps, temperature=cfg.temperature,
+        top_k=cfg.top_k,
     )
+
+
+def _tv_for(cfg, logits, ref, n_runs=300, seed=0):
+    step = _sampler(cfg, logits)
     counts = np.zeros(logits.shape[1])
     for k in jax.random.split(jax.random.PRNGKey(seed), n_runs):
-        counts[int(sample(k)[0])] += 1
+        counts[int(step(k)[0][0])] += 1
     emp = counts / counts.sum()
     return float(0.5 * np.abs(emp - ref).sum())
 
 
 def _latency_us(cfg, logits, reps=20, seed=0):
-    sample = jax.jit(
-        lambda k: token_sampler.sample_tokens(k, logits, cfg).tokens
-    )
+    step = _sampler(cfg, logits)
     keys = jax.random.split(jax.random.PRNGKey(seed), reps + 1)
-    jax.block_until_ready(sample(keys[0]))  # compile
+    jax.block_until_ready(step(keys[0]))  # compile
     t0 = time.perf_counter()
     for k in keys[1:]:
-        out = sample(k)
+        out = step(k)[0]
     jax.block_until_ready(out)
     return (time.perf_counter() - t0) / reps * 1e6
 
@@ -96,12 +103,11 @@ def run() -> list[dict]:
             vocab_size=vocab, n_steps=64, randomness=randomness
         )
         tv = _tv_for(cfg, logits, ref_full, n_runs)
-        sample = jax.jit(
-            lambda k: token_sampler.sample_tokens(k, logits, cfg).acceptance_rate
-        )
-        acc = float(
-            np.mean([sample(k) for k in jax.random.split(jax.random.PRNGKey(1), 32)])
-        )
+        step = _sampler(cfg, logits)
+        acc = float(np.mean([
+            step(k)[1].acceptance_rate
+            for k in jax.random.split(jax.random.PRNGKey(1), 32)
+        ]))
         rows.append(
             {
                 "bench": "token_sampler_randomness",

@@ -27,10 +27,8 @@ from __future__ import annotations
 import dataclasses
 import math
 import warnings
-from functools import partial
 from typing import NamedTuple
 
-import jax
 import jax.numpy as jnp
 
 from repro import samplers
@@ -75,13 +73,15 @@ class TokenSampleResult(NamedTuple):
     final_logp: Array        # (batch,) float32 unnormalised log-prob
 
 
-@partial(jax.jit, static_argnames=("cfg",))
 def _sample_tokens_impl(
     key,
     logits: Array,
     cfg: TokenSamplerConfig,
     init_tokens: Array | None = None,
 ) -> TokenSampleResult:
+    """One decode step through ``MHEngine.sample_tokens``: from the host
+    one compiled program per (config, shapes), the logits traced; inside
+    a caller's trace, staged into it."""
     engine = samplers.MHEngine(cfg.engine_config())
     tokens, result = engine.sample_tokens(
         key,

@@ -16,7 +16,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import rng
-from repro.kernels.mh.mh import mh_chain_pallas, mh_chain_pallas_fused
+from repro.kernels.mh.mh import (
+    lookup_by_window,
+    mh_chain_pallas,
+    mh_chain_pallas_fused,
+)
 
 
 def _on_tpu() -> bool:
@@ -27,6 +31,16 @@ def _round_up(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
 
 
+def _chain_block(table, c: int, block_c: int) -> tuple[int, int]:
+    """(block, padded C) of the kernel's chain axis.  The window path
+    flattens and pads (row, chain) lanes itself, so its chain axis
+    stays as it is."""
+    if lookup_by_window(table.shape):
+        return block_c, c
+    bc = min(block_c, _round_up(c, 128))
+    return bc, _round_up(c, bc)
+
+
 def mh_sample(table, init, flips, u, nbits: int, block_c: int = 256):
     """Pad the chain axis to a lane multiple and run the fused kernel.
 
@@ -34,8 +48,7 @@ def mh_sample(table, init, flips, u, nbits: int, block_c: int = 256):
     (``_drive_pallas_chunks``) slices what its collection mode keeps
     into a preallocated stream buffer (DESIGN.md §Collection)."""
     b, c = init.shape
-    bc = min(block_c, _round_up(c, 128))
-    c_pad = _round_up(c, bc)
+    bc, c_pad = _chain_block(table, c, block_c)
     if c_pad != c:
         pad = c_pad - c
         init = jnp.pad(init, ((0, 0), (0, pad)))
@@ -65,8 +78,7 @@ def mh_sample_fused(
     stream and are sliced off like the operand path's u=1.0 padding."""
     b, c = init.shape
     t0c = jnp.broadcast_to(jnp.asarray(t0, jnp.int32), (c,))
-    bc = min(block_c, _round_up(c, 128))
-    c_pad = _round_up(c, bc)
+    bc, c_pad = _chain_block(table, c, block_c)
     if c_pad != c:
         pad = c_pad - c
         init = jnp.pad(init, ((0, 0), (0, pad)))

@@ -1098,17 +1098,63 @@ class MHEngine:
         Returns (tokens (B,) int32, full EngineResult).  ``init_tokens``
         seeds the chains (the macro's x^(0) written into the bitcells);
         defaults to the row argmax — a guaranteed finite-logp start.
+
+        A decode step brings new logits every call, so it runs as one
+        compiled program keyed on the engine's configuration and the
+        shapes, with the logits a traced argument: a new table never
+        recompiles (DESIGN.md §Run-API); inside a caller's trace that
+        program is staged into the caller's.  On the host the call
+        records a ``tokens.sample`` span and adds its rows and
+        chain-steps to the ``tokens_rows_total`` and
+        ``tokens_steps_total`` counters (DESIGN.md §Telemetry).
         """
-        target = logits_target(logits, temperature=temperature, top_k=top_k)
-        if init_tokens is None:
-            init = jnp.argmax(target.table, axis=-1).astype(jnp.uint32)
-        else:
-            init = jnp.clip(
-                init_tokens.astype(jnp.uint32), 0, target.table.shape[-1] - 1
+        rows = int(jnp.shape(logits)[0])
+        with _host_spans()("tokens.sample", rows=rows, n_steps=n_steps):
+            out = _sample_tokens_compiled(
+                key, logits, init_tokens, config=self.config,
+                n_steps=n_steps, temperature=temperature, top_k=top_k,
             )
+        if _host_side():
+            telemetry.counter(
+                "tokens_rows_total", "rows sampled by sample_tokens"
+            ).inc(rows)
+            telemetry.counter(
+                "tokens_steps_total", "MH chain-steps run by sample_tokens"
+            ).inc(rows * n_steps)
+        return out
+
+    def _sample_tokens(
+        self, key, logits, n_steps: int, temperature: float, top_k: int,
+        init_tokens,
+    ) -> tuple[Array, EngineResult]:
+        with jax.named_scope("tokens.table"):
+            target = logits_target(
+                logits, temperature=temperature, top_k=top_k
+            )
+            if init_tokens is None:
+                init = jnp.argmax(target.table, axis=-1).astype(jnp.uint32)
+            else:
+                init = jnp.clip(
+                    init_tokens.astype(jnp.uint32), 0,
+                    target.table.shape[-1] - 1,
+                )
         result = self.run(key, target, n_steps, init[:, None])
         tokens = target.decode(result.final_words)[:, 0].astype(jnp.int32)
         return tokens, result
+
+
+@functools.partial(
+    jax.jit, static_argnames=("config", "n_steps", "temperature", "top_k")
+)
+def _sample_tokens_compiled(
+    key, logits, init_tokens, *, config, n_steps, temperature, top_k
+):
+    """The decode step's one program: ``sample_tokens`` staged for an
+    engine of ``config`` (a frozen dataclass, hashed by value, so every
+    engine of one configuration shares the program)."""
+    return MHEngine(config)._sample_tokens(
+        key, logits, n_steps, temperature, top_k, init_tokens
+    )
 
 
 SamplerEngine = MHEngine  # the engine outgrew its MH-only name in PR 2

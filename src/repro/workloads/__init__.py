@@ -25,7 +25,7 @@ from typing import Callable
 import numpy as np
 
 from repro import diagnostics, samplers
-from repro.workloads import gmm, ising, spin_glass
+from repro.workloads import gmm, ising, spin_glass, tokens
 
 
 @dataclasses.dataclass
@@ -153,6 +153,7 @@ WORKLOADS = {
     "ising": ising.build,
     "gmm": gmm.build,
     "spin_glass": spin_glass.build,
+    "tokens": tokens.build,
 }
 
 

@@ -5,8 +5,9 @@ Mosaic compiler refuses: unaligned block shapes, lane gathers, uint32
 -> float32 casts, too much VMEM.  These tests compile each kernel with
 ``interpret=False`` for a *described* v5e chip — nothing runs, and no
 TPU is needed — at the widths the workloads deploy: the gmm table
-(V=256) with 4096 compartment chains, and 8 lattices of 128x128 with
-one 32-step chunk.
+(V=256) with 4096 compartment chains, a decode step's (256, 129,280)
+logits table with one chain a row, and 8 lattices of 128x128 with one
+32-step chunk.
 
 The topology is described inside a module fixture (never at import):
 only one process may load the TPU library, and pytest-xdist workers
@@ -23,11 +24,17 @@ from repro.kernels.gibbs.gibbs import (
     gibbs_chain_pallas,
     gibbs_chain_pallas_fused,
 )
-from repro.kernels.mh.mh import mh_chain_pallas, mh_chain_pallas_fused
+from repro.kernels.mh.mh import (
+    VOCAB_KERNEL,
+    mh_chain_pallas,
+    mh_chain_pallas_fused,
+)
 from repro.workloads.ising import IsingModel
 from repro.workloads.spin_glass import SpinGlass
 
 V, C, BLOCK_C = 256, 4096, 256      # gmm nbits=8 table, compartment chains
+ROWS, VOCAB = 256, 129_280          # decode batch x DeepSeek-V3 vocabulary
+TOKEN_K = 64                        # MH steps per token
 LATTICES, SIDE = 8, 128             # Gibbs lattice batch, 128x128 sites
 K = 32                              # steps per chunk (workload default)
 
@@ -88,6 +95,49 @@ def test_mh_fused_compiles(chip):
         ((1, V), jnp.float32), ((1, C), jnp.uint32),
         ((C,), jnp.uint32), ((C,), jnp.uint32), ((C,), jnp.int32),
     ))
+
+
+# One chain per row of a decode step's (256, 129,280) logits, and of a
+# small (8, 128) table: both take the window lookup (B > 1 rows), whose
+# table stays in HBM.
+TOKEN_TABLES = [(ROWS, VOCAB), (8, 128)]
+
+
+@pytest.mark.parametrize("rows,vocab", TOKEN_TABLES)
+def test_mh_vocab_operand_compiles(chip, rows, vocab):
+    nbits = (vocab - 1).bit_length()
+
+    def run(table, init, flips, u):
+        return mh_chain_pallas(
+            table, init, flips, u, nbits=nbits, interpret=False
+        )
+
+    compiled = _compile(
+        run, chip,
+        ((rows, vocab), jnp.float32), ((rows, 1), jnp.uint32),
+        ((TOKEN_K, rows, 1), jnp.uint32), ((TOKEN_K, rows, 1), jnp.float32),
+    )
+    _assert_kernel(compiled)
+    assert VOCAB_KERNEL in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows,vocab", TOKEN_TABLES)
+def test_mh_vocab_fused_compiles(chip, rows, vocab):
+    nbits = (vocab - 1).bit_length()
+
+    def run(table, init, k0c, k1c, t0c):
+        return mh_chain_pallas_fused(
+            table, init, k0c, k1c, t0c, nbits=nbits, n_steps=TOKEN_K, cc=1,
+            p_u32=rng.threshold_u32(0.45), interpret=False,
+        )
+
+    compiled = _compile(
+        run, chip,
+        ((rows, vocab), jnp.float32), ((rows, 1), jnp.uint32),
+        ((1,), jnp.uint32), ((1,), jnp.uint32), ((1,), jnp.int32),
+    )
+    _assert_kernel(compiled)
+    assert VOCAB_KERNEL in compiled.as_text()
 
 
 def _lattice_model(name):
