@@ -271,7 +271,9 @@ def _mh_step(target, nbits: int, words, logp, acc, flip, u):
         return words, logp, acc + accept.astype(jnp.int32)
 
 
-def _run_scan_chunked(make_xs, step_fn, carry, n_steps, chunk, step0, collect):
+def _run_scan_chunked(
+    make_xs, step_fn, carry, n_steps, chunk, step0, collect, unroll=1
+):
     """THE scan-side chunk scheduler — the full/remainder scaffolding both
     scan executors share (mh and gibbs differ only in their operand maker
     and step body).
@@ -284,7 +286,11 @@ def _run_scan_chunked(make_xs, step_fn, carry, n_steps, chunk, step0, collect):
     slice (chunks are k-aligned by ``_effective_chunk``, so every full
     chunk keeps the same row count and the outer scan stays shape-static),
     and "last" emits nothing — the inner scan carries only the state, so
-    output memory is O(state) for any chain length.
+    output memory is O(state) for any chain length.  ``unroll`` unrolls
+    the inner scan where it stays a loop, at least two unrolled
+    iterations: shorter, it is straight-line code, in which XLA's CPU
+    backend fuses the chained steps' cipher draws into kernels it takes
+    minutes to compile (the stream and the result do not depend on it).
     """
     mode, k = collect
     chunk = _effective_chunk(n_steps, chunk, k if mode == "thin" else None)
@@ -296,7 +302,9 @@ def _run_scan_chunked(make_xs, step_fn, carry, n_steps, chunk, step0, collect):
             c = step_fn(c, x)
             return c, (None if mode == "last" else c[0])
 
-        c, ys = jax.lax.scan(body, c, make_xs(start, n))
+        c, ys = jax.lax.scan(
+            body, c, make_xs(start, n), unroll=unroll if n >= 2 * unroll else 1
+        )
         if mode == "thin":
             # start ≡ step0 (mod k) for every span, so the kept offset is
             # the same static i0 and the slice shape is chunk-invariant
@@ -542,6 +550,21 @@ def _run_pallas(
     )
 
 
+def _count_step_draws(engine, target, n_steps: int) -> None:
+    """Add one engine call's scan Gibbs half-sweeps, each of which draws
+    its uniforms in the step, to the ``gibbs_step_draw_total`` counter —
+    on the host only, once per call (DESIGN.md §Telemetry); a traced
+    re-entry counts nothing."""
+    cfg = engine.config
+    if cfg.update == "gibbs" and _host_side() and resolve_execution(
+        cfg.execution, target, "gibbs"
+    ) == "scan":
+        telemetry.counter(
+            "gibbs_step_draw_total",
+            "Gibbs half-sweeps whose uniforms the scan step drew itself",
+        ).inc(n_steps)
+
+
 def _gibbs_step(target, state, acc, u, parity):
     """THE Gibbs half-sweep — the only scan-side implementation in the repo.
 
@@ -562,22 +585,25 @@ def _gibbs_step(target, state, acc, u, parity):
 def _run_scan_gibbs(
     key, target, backend, n_steps, chunk, step0, init_words, collect
 ):
+    """Scan Gibbs.  Each half-sweep draws its own uniforms in the step
+    body (``backend.step``, the chunk draw's stream bit for bit), so no
+    (chunk, B, H, W) operand exists and XLA fuses the cipher, the
+    conditional, the select and the flip count into one pass over the
+    lattice (DESIGN.md §2)."""
     shape = init_words.shape
     carry = (init_words.astype(jnp.uint32), jnp.zeros(shape, jnp.int32))
 
     def make_xs(start, n):
+        return start + jnp.arange(n, dtype=jnp.int32)
+
+    def step_fn(c, t):
         # gibbs draws no proposal — the operand-lean u-only path
         with jax.named_scope("gibbs.draw"):
-            _, u = backend.chunk(key, start, n, shape, 1, need_flips=False)
-            idx = start + jnp.arange(n, dtype=jnp.int32)
-            return (u, idx)
-
-    def step_fn(c, x):
-        u_t, t = x
-        return _gibbs_step(target, *c, u_t, t % 2)
+            _, u = backend.step(key, t, shape, 1, need_flips=False)
+        return _gibbs_step(target, *c, u, t % 2)
 
     samples, (state, acc) = _run_scan_chunked(
-        make_xs, step_fn, carry, n_steps, chunk, step0, collect
+        make_xs, step_fn, carry, n_steps, chunk, step0, collect, unroll=2
     )
     return samples, acc, state
 
@@ -909,6 +935,9 @@ class MHEngine:
         concrete ``jax.sharding.Mesh``) shards the chain axis across
         devices via ``shard_map`` under the "chains" sharding rule;
         chains never communicate, so sharded == unsharded bit-for-bit.
+
+        On the host, a scan Gibbs call adds its half-sweeps to the
+        ``gibbs_step_draw_total`` counter (DESIGN.md §Telemetry).
         """
         if n_steps < 1:
             raise ValueError(f"n_steps must be >= 1, got {n_steps}")
@@ -923,6 +952,7 @@ class MHEngine:
                 "carry holds no log-prob and the chains axis derives its "
                 "own per-chain carries"
             )
+        _count_step_draws(self, target, n_steps)
         if self.config.num_chains > 1:
             return self._run_chains(
                 key, target, n_steps, init_words, mesh, base=chain_id,

@@ -42,6 +42,7 @@ from repro import telemetry
 from repro.samplers.engine import (
     EngineResult,
     MHEngine,
+    _count_step_draws,
     _host_side,
     parse_collect,
     resolve_execution,
@@ -395,6 +396,8 @@ def submit(engine: MHEngine, plan: RunPlan, *, compiled: bool = False):
                 result = dispatcher(*args, **kw)
                 after = dispatcher._cache_size()
                 sp.set(jit_cache="miss" if after > before else "hit")
+        # engine.run ran traced inside the dispatcher: count on the host
+        _count_step_draws(engine, plan.target, int(plan.n_steps))
     else:
         run_args = (key, plan.target, plan.n_steps, plan.init_words)
         run_kw = dict(

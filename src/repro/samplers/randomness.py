@@ -19,16 +19,17 @@ Three backends implement the same ``RandomnessBackend`` protocol
     generated inside the thing doing the sampling.  Under pallas
     execution the fused kernels derive every operand in-kernel from a
     counter cipher (kernels/rng) keyed on ``(chain key, absolute step,
-    site)`` — zero per-step operand traffic; this backend's ``chunk`` is
+    site)`` — zero per-step operand traffic; this backend's ``step`` is
     the scan-side *reference* that draws the identical stream through
     the same shared functions, so {scan, pallas} stay bit-exact.
 
 Chunked streaming contract (DESIGN.md §2): the operands for step ``t``
-depend only on ``(key, t)`` — host/cim derive per-step keys via
-``jax.random.fold_in(key, t)``, fused folds ``t`` into the counter
-cipher — so a chain may be generated in chunks of any size and the
-resulting stream is *bit-identical* to the monolithic (K, B, C)
-materialisation.  Long chains are therefore memory-bounded by the chunk
+depend only on ``(key, t)`` — each backend's ``step`` draws them,
+host/cim from the per-step key ``jax.random.fold_in(key, t)``, fused by
+folding ``t`` into the counter cipher, and ``chunk`` is ``step`` vmapped
+— so a chain may be generated in chunks of any size, or a step at a
+time inside a scan body, and the resulting stream is *bit-identical* to
+the monolithic (K, B, C) materialisation.  Long chains are therefore memory-bounded by the chunk
 size, not the chain length.
 
 One program per chunk (DESIGN.md §Randomness): every backend's
@@ -81,17 +82,20 @@ def chain_keys(key, num_chains: int, base: int = 0) -> Array:
     return jax.vmap(lambda c: chain_key(key, c))(ids)
 
 
-def step_keys(key, start, n_steps: int) -> Array:
-    """Per-step keys for absolute steps [start, start + n_steps)."""
-    ts = jnp.asarray(start, jnp.int32) + jnp.arange(n_steps, dtype=jnp.int32)
-    return jax.vmap(lambda t: jax.random.fold_in(key, t))(ts)
-
-
 @runtime_checkable
 class RandomnessBackend(Protocol):
     """Produces the (flips, u) operand stream for a span of MH steps."""
 
     name: str
+
+    def step(
+        self, key, t, shape: tuple, nbits: int, need_flips: bool = True,
+    ) -> tuple[Array | None, Array]:
+        """Operands for the one absolute step ``t`` (may be traced):
+        ``(flips shape uint32, u shape float32)``, or ``(None, u)`` under
+        ``need_flips=False``.  A function of ``(key, t, shape)`` alone,
+        so a scan step may draw its own operands where it uses them."""
+        ...
 
     def chunk(
         self, key, start, n_steps: int, shape: tuple, nbits: int,
@@ -125,9 +129,20 @@ def draw_cache_size() -> int:
 
 
 class _CompiledChunk:
-    """The shared ``chunk`` of the three backends: their ``draw`` body,
-    compiled once per static signature (``_chunk_program``).  Backends
-    are frozen dataclasses, so equal backends share the cache."""
+    """The shared ``draw`` and ``chunk`` of the three backends.  ``draw``
+    is their ``step`` vmapped over steps [start, start + n_steps), so a
+    chunk holds exactly the operands the steps would draw one by one;
+    ``chunk`` runs it compiled once per static signature
+    (``_chunk_program``).  Backends are frozen dataclasses, so equal
+    backends share the cache."""
+
+    def draw(self, key, start, n_steps, shape, nbits, need_flips):
+        ts = jnp.asarray(start, jnp.int32) + jnp.arange(
+            n_steps, dtype=jnp.int32
+        )
+        return jax.vmap(
+            lambda t: self.step(key, t, shape, nbits, need_flips)
+        )(ts)
 
     def chunk(self, key, start, n_steps, shape, nbits, need_flips=True):
         return _chunk_program(
@@ -144,23 +159,19 @@ class HostRandomness(_CompiledChunk):
 
     name = "host"
 
-    def draw(self, key, start, n_steps, shape, nbits, need_flips):
-        def one(k):
-            k_flip, k_u = jax.random.split(k)
-            u = jax.random.uniform(k_u, shape, jnp.float32)
-            if not need_flips:
-                return u
-            planes = jax.random.bernoulli(k_flip, self.p_bfr, (*shape, nbits))
-            weights = (
-                jnp.uint32(1) << jnp.arange(nbits, dtype=jnp.uint32)
-            ).astype(jnp.uint32)
-            flips = jnp.sum(
-                planes.astype(jnp.uint32) * weights, axis=-1
-            ).astype(jnp.uint32)
-            return flips, u
-
-        out = jax.vmap(one)(step_keys(key, start, n_steps))
-        return out if need_flips else (None, out)
+    def step(self, key, t, shape, nbits, need_flips=True):
+        k_flip, k_u = jax.random.split(jax.random.fold_in(key, t))
+        u = jax.random.uniform(k_u, shape, jnp.float32)
+        if not need_flips:
+            return None, u
+        planes = jax.random.bernoulli(k_flip, self.p_bfr, (*shape, nbits))
+        weights = (
+            jnp.uint32(1) << jnp.arange(nbits, dtype=jnp.uint32)
+        ).astype(jnp.uint32)
+        flips = jnp.sum(
+            planes.astype(jnp.uint32) * weights, axis=-1
+        ).astype(jnp.uint32)
+        return flips, u
 
 
 @dataclasses.dataclass(frozen=True)
@@ -174,21 +185,17 @@ class CIMRandomness(_CompiledChunk):
 
     name = "cim"
 
-    def draw(self, key, start, n_steps, shape, nbits, need_flips):
-        def one(k):
-            k_flip, k_u = jax.random.split(k)
-            u = uniform_rng.uniform(
-                k_u, shape, self.rng_p_bfr, self.rng_bit_width, self.rng_stages
-            )
-            if not need_flips:
-                return u
-            flips = bitcell.raw_random_words(
-                k_flip, self.p_bfr, shape, nbits=nbits
-            )
-            return flips, u
-
-        out = jax.vmap(one)(step_keys(key, start, n_steps))
-        return out if need_flips else (None, out)
+    def step(self, key, t, shape, nbits, need_flips=True):
+        k_flip, k_u = jax.random.split(jax.random.fold_in(key, t))
+        u = uniform_rng.uniform(
+            k_u, shape, self.rng_p_bfr, self.rng_bit_width, self.rng_stages
+        )
+        if not need_flips:
+            return None, u
+        flips = bitcell.raw_random_words(
+            k_flip, self.p_bfr, shape, nbits=nbits
+        )
+        return flips, u
 
 
 @dataclasses.dataclass(frozen=True)
@@ -199,9 +206,9 @@ class FusedRandomness(_CompiledChunk):
     s) = Threefry-2x32 of the ``(t, s)`` counter under the chain key's
     two uint32 words, salted per operand.  Under pallas execution the
     fused kernels make exactly these draws *inside* the kernel — no
-    operand tensors exist; this ``chunk`` materialises the identical
-    values through the same shared functions for the scan executors
-    (and for the tempering swap test), keeping the engine's bit-parity
+    operand tensors exist; this ``step`` draws the identical values
+    through the same shared functions for the scan executors (and
+    ``chunk`` for the tempering swap test), keeping the engine's bit-parity
     contract alive across {scan, pallas} (tests/test_fused_rng.py).
     """
 
@@ -209,23 +216,14 @@ class FusedRandomness(_CompiledChunk):
 
     name = "fused"
 
-    def draw(self, key, start, n_steps, shape, nbits, need_flips):
-        k0, k1 = rng.key_words(key)
+    def step(self, key, t, shape, nbits, need_flips=True):
+        s0, s1 = rng.step_key(*rng.key_words(key), t)
         site = rng.site_index(shape)
+        u = rng.uniform_at(s0, s1, site)
+        if not need_flips:
+            return None, u
         p_u32 = rng.threshold_u32(self.p_bfr)
-
-        def one(t):
-            s0, s1 = rng.step_key(k0, k1, t)
-            u = rng.uniform_at(s0, s1, site)
-            if not need_flips:
-                return u
-            return rng.flips_at(s0, s1, site, nbits, p_u32), u
-
-        ts = jnp.asarray(start, jnp.int32) + jnp.arange(
-            n_steps, dtype=jnp.int32
-        )
-        out = jax.vmap(one)(ts)
-        return out if need_flips else (None, out)
+        return rng.flips_at(s0, s1, site, nbits, p_u32), u
 
 
 def make_randomness_backend(

@@ -29,6 +29,38 @@ from repro import samplers
 Array = jnp.ndarray
 
 
+def spin(words: Array) -> Array:
+    """{0, 1} words as float32 spins -1/+1, via int32: Mosaic has no
+    uint32 -> float32 cast."""
+    return 2.0 * words.astype(jnp.int32).astype(jnp.float32) - 1.0
+
+
+def periodic_roll(x: Array, shift: int, axis: int) -> Array:
+    """``jnp.roll(x, shift, axis)``, value for value, built from
+    positive pads and static slices.
+
+    A lattice model's conditional rolls the carried state, and this form
+    lets XLA fuse every roll into the half-sweep's one loop over the
+    lattice: ``jnp.roll``'s concatenate of slices materialises the
+    slices in HBM when the roll runs along the lane axis.  Mosaic lowers
+    the same ops inside the Gibbs kernels (DESIGN.md §2)."""
+    axis %= x.ndim
+    n = x.shape[axis]
+    k = shift % n
+    if k == 0:
+        return x
+
+    def moved(lo: int, hi: int, start: int) -> Array:
+        pads = [(0, 0, 0)] * x.ndim
+        pads[axis] = (lo, hi, 0)
+        padded = jax.lax.pad(x, jnp.zeros((), x.dtype), pads)
+        return jax.lax.slice_in_dim(padded, start, start + n, axis=axis)
+
+    # i >= k reads x[i - k]; i < k wraps round to x[n - k + i]
+    idx = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    return jnp.where(idx < k, moved(0, n - k, n - k), moved(k, 0, 0))
+
+
 @dataclasses.dataclass(frozen=True)
 class IsingModel:
     """Ferromagnetic 2-D Ising model on a periodic H x W lattice.
@@ -70,13 +102,11 @@ class IsingModel:
         scan executor steps it and the fused kernel traces it (it rides
         a jit static argument, hence the frozen dataclass).
         """
-        # {0, 1} words via int32: Mosaic has no uint32 -> float32 cast
-        s = 2.0 * state.astype(jnp.int32).astype(jnp.float32) - 1.0
         nb = (
-            jnp.roll(s, 1, -2)
-            + jnp.roll(s, -1, -2)
-            + jnp.roll(s, 1, -1)
-            + jnp.roll(s, -1, -1)
+            spin(periodic_roll(state, 1, -2))
+            + spin(periodic_roll(state, -1, -2))
+            + spin(periodic_roll(state, 1, -1))
+            + spin(periodic_roll(state, -1, -1))
         )
         return 2.0 * (self.beta * nb + self.field)
 

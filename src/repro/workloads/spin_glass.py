@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import samplers
+from repro.workloads.ising import periodic_roll, spin
 
 Array = jnp.ndarray
 
@@ -131,13 +132,11 @@ class SpinGlass:
     def fused_logit(self, state: Array, j_right, j_down) -> Array:
         """Per-site logit of s_i = +1 given the neighbours:
         2 (sum_j J_ij s_j + field), each incident bond with its own J."""
-        # {0, 1} words via int32: Mosaic has no uint32 -> float32 cast
-        s = 2.0 * state.astype(jnp.int32).astype(jnp.float32) - 1.0
         nb = (
-            j_right * jnp.roll(s, -1, -1)
-            + jnp.roll(j_right, 1, -1) * jnp.roll(s, 1, -1)
-            + j_down * jnp.roll(s, -1, -2)
-            + jnp.roll(j_down, 1, -2) * jnp.roll(s, 1, -2)
+            j_right * spin(periodic_roll(state, -1, -1))
+            + periodic_roll(j_right, 1, -1) * spin(periodic_roll(state, 1, -1))
+            + j_down * spin(periodic_roll(state, -1, -2))
+            + periodic_roll(j_down, 1, -2) * spin(periodic_roll(state, 1, -2))
         )
         return 2.0 * (nb + self.field)
 

@@ -32,6 +32,8 @@ from hypothesis import given, settings, strategies as st
 
 from repro import samplers, tempering
 from repro.kernels import rng
+from repro.samplers import engine as engine_mod
+from repro.serving import PackedExecutor, ServeRequest
 from repro.workloads.ising import IsingModel
 from repro.workloads.spin_glass import SpinGlass
 
@@ -66,6 +68,40 @@ def _gibbs_case(batch=2):
 
 def _case(update):
     return _mh_case() if update == "mh" else _gibbs_case()
+
+
+# The lattice targets the scan Gibbs step serves: a model, a model whose
+# couplings are arrays, and a tempering-ladder replica of a model.
+GIBBS_TARGETS = {
+    "ising": lambda: IsingModel(height=4, width=6),
+    "spin_glass": lambda: SpinGlass.bimodal(jax.random.PRNGKey(1), 4, 6),
+    "tempered": lambda: tempering.Ladder((1.0, 0.6)).targets(
+        IsingModel(height=4, width=6)
+    )[1],
+}
+
+
+def _chunk_draw_reference(backend, key, target, init, n_steps, step0,
+                          collect):
+    """(samples, accept_count, final_words) of one chain the way the scan
+    Gibbs executor ran before it drew in the step: the backend's chunk
+    of uniforms for the whole run, then ``_gibbs_step`` once a step."""
+    _, u = backend.chunk(key, step0, n_steps, init.shape, 1,
+                         need_flips=False)
+    parity = (step0 + jnp.arange(n_steps, dtype=jnp.int32)) % 2
+
+    def body(c, x):
+        c = engine_mod._gibbs_step(target, *c, *x)
+        return c, c[0]
+
+    carry = (init.astype(jnp.uint32), jnp.zeros(init.shape, jnp.int32))
+    (final, acc), states = jax.lax.scan(body, carry, (u, parity))
+    mode, k = engine_mod.parse_collect(collect)
+    if mode == "thin":
+        states = states[(-step0) % k::k]
+    elif mode == "last":
+        states = states[:0]
+    return states, acc, final
 
 
 class TestThreefryKnownAnswers:
@@ -144,6 +180,103 @@ class TestFusedParityMatrix:
             np.testing.assert_array_equal(
                 np.asarray(multi.samples[c]), np.asarray(r.samples)
             )
+
+    @pytest.mark.parametrize("target_name", sorted(GIBBS_TARGETS))
+    @pytest.mark.parametrize("step0", [6, 9])
+    @pytest.mark.parametrize("collect", ["all", "thin:3", "last"])
+    @pytest.mark.parametrize("num_chains", [1, 4])
+    def test_in_step_draw_matches_chunk_draw(
+        self, target_name, step0, collect, num_chains
+    ):
+        """The scan Gibbs step draws its fused uniforms itself; every
+        result stays the chunk draw's, bit for bit (13 steps in chunks
+        of 4, so a remainder chunk runs too)."""
+        target = GIBBS_TARGETS[target_name]()
+        inits = [target.random_init(jax.random.PRNGKey(3 + c), 2)
+                 for c in range(num_chains)]
+        key = jax.random.PRNGKey(13)
+        engine = _engine(update="gibbs", execution="scan", chunk_steps=4,
+                         num_chains=num_chains)
+        init = inits[0] if num_chains == 1 else jnp.stack(inits)
+        out = engine.run(key, target, 13, init, step0=step0, collect=collect)
+        for c in range(num_chains):
+            samples, acc, final = _chunk_draw_reference(
+                engine.randomness, samplers.chain_key(key, c), target,
+                inits[c], 13, step0, collect,
+            )
+            pick = (lambda x: x) if num_chains == 1 else (lambda x: x[c])
+            np.testing.assert_array_equal(pick(out.samples), samples)
+            np.testing.assert_array_equal(pick(out.accept_count), acc)
+            np.testing.assert_array_equal(pick(out.final_words), final)
+            np.testing.assert_array_equal(
+                pick(out.final_logp), engine_mod._gibbs_logp(target, final)
+            )
+
+    @pytest.mark.parametrize("randomness", ["host", "cim"])
+    def test_host_and_cim_gibbs_keep_their_stream(self, randomness):
+        """Host and cim draw in the scan Gibbs step too: a step's draw
+        depends only on (key, t, shape), so their runs stream exactly
+        what the chunk draw gave them."""
+        target, init = _gibbs_case()
+        key = jax.random.PRNGKey(21)
+        engine = samplers.MHEngine(samplers.EngineConfig(
+            update="gibbs", randomness=randomness, execution="scan",
+            chunk_steps=4,
+        ))
+        out = engine.run(key, target, 11, init, step0=5)
+        samples, acc, final = _chunk_draw_reference(
+            engine.randomness, samplers.chain_key(key, 0), target, init, 11,
+            5, "all",
+        )
+        np.testing.assert_array_equal(out.samples, samples)
+        np.testing.assert_array_equal(out.accept_count, acc)
+        np.testing.assert_array_equal(out.final_words, final)
+
+    @pytest.mark.parametrize("randomness", ["host", "cim", "fused"])
+    @pytest.mark.parametrize("need_flips", [True, False])
+    def test_chunk_is_step_stacked(self, randomness, need_flips):
+        """Every backend's chunk over [start, start + n) holds exactly
+        the operands its ``step`` draws at each absolute step, which is
+        what lets a scan step draw its own."""
+        backend = samplers.make_randomness_backend(randomness, p_bfr=0.3)
+        key, shape = jax.random.PRNGKey(8), (2, 3, 5)
+        flips, u = backend.chunk(key, 7, 4, shape, 6, need_flips=need_flips)
+        for i in range(4):
+            f_t, u_t = backend.step(key, 7 + i, shape, 6,
+                                    need_flips=need_flips)
+            np.testing.assert_array_equal(u[i], u_t)
+            if need_flips:
+                np.testing.assert_array_equal(flips[i], f_t)
+            else:
+                assert flips is None and f_t is None
+
+    def test_serving_slots_at_both_parities(self):
+        """Two served requests a segment apart (3 steps, so one slot
+        sits at an odd step when the other joins at step 0) each stream
+        the chunk draw's result through the vmapped class program."""
+        ex = PackedExecutor.for_workload(
+            "ising", n_slots=2, randomness="fused", execution="scan",
+            smoke=True, chunk_steps=3,
+        )
+        a = ServeRequest(rid=0, workload="ising", n_steps=12, seed=5,
+                         collect="all")
+        ex.admit(a)
+        ex.advance_chunk()
+        b = ServeRequest(rid=1, workload="ising", n_steps=9, seed=6,
+                         collect="all")
+        ex.admit(b)
+        while ex.active_count:
+            ex.advance_chunk()
+        ex.drain()
+        for req in (a, b):
+            init, k_run, _ = ex.request_init(req)
+            samples, acc, final = _chunk_draw_reference(
+                ex.engine.randomness, samplers.chain_key(k_run, 0),
+                ex.target, init, req.n_steps, 0, "all",
+            )
+            np.testing.assert_array_equal(req.samples, samples)
+            np.testing.assert_array_equal(req.accept_count, acc)
+            np.testing.assert_array_equal(req.final_words, final)
 
     def test_fused_distinct_from_host_and_cim(self):
         target, init = _mh_case()

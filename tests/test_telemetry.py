@@ -399,6 +399,34 @@ class TestInstrumentation:
             np.asarray(off.final_logp), np.asarray(on.final_logp)
         )
 
+    @pytest.mark.parametrize(
+        "randomness,execution,compiled,drawn",
+        [
+            pytest.param("fused", "scan", False, 13, id="fused"),
+            pytest.param("fused", "scan", True, 13, id="fused-compiled"),
+            pytest.param("cim", "scan", False, 13, id="cim"),
+            pytest.param("fused", "pallas", False, 0, id="fused-pallas"),
+        ],
+    )
+    def test_gibbs_step_draw_counter(
+        self, randomness, execution, compiled, drawn
+    ):
+        """A scan Gibbs call adds its half-sweeps, once, whether the
+        engine runs on the host or traced inside the compiled submit;
+        under pallas the kernel draws, and the counter adds nothing."""
+        target, init = _gibbs_setup()
+        engine = samplers.MHEngine(samplers.EngineConfig(
+            update="gibbs", randomness=randomness, execution=execution,
+            chunk_steps=4,
+        ))
+        plan = samplers.RunPlan(
+            target=target, n_steps=13, init_words=init, seed=3
+        )
+        drawn_total = telemetry.counter("gibbs_step_draw_total")
+        before = drawn_total.value()
+        engine.submit(plan, compiled=compiled)
+        assert drawn_total.value() - before == drawn
+
     def test_submit_span_carries_plan_metadata(self):
         target, init = _mh_setup()
         engine = samplers.MHEngine(samplers.EngineConfig(chunk_steps=8))

@@ -7,7 +7,8 @@ Mosaic compiler refuses: unaligned block shapes, lane gathers, uint32
 TPU is needed — at the widths the workloads deploy: the gmm table
 (V=256) with 4096 compartment chains, a decode step's (256, 129,280)
 logits table with one chain a row, and 8 lattices of 128x128 with one
-32-step chunk.
+32-step chunk; and the scan Gibbs job program at 8 lattices of
+1024x1024.
 
 The topology is described inside a module fixture (never at import):
 only one process may load the TPU library, and pytest-xdist workers
@@ -19,6 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import samplers
 from repro.kernels import rng
 from repro.kernels.gibbs.gibbs import (
     gibbs_chain_pallas,
@@ -29,6 +31,7 @@ from repro.kernels.mh.mh import (
     mh_chain_pallas,
     mh_chain_pallas_fused,
 )
+from repro.samplers.plan import _submit_compiled
 from repro.workloads.ising import IsingModel
 from repro.workloads.spin_glass import SpinGlass
 
@@ -36,6 +39,7 @@ V, C, BLOCK_C = 256, 4096, 256      # gmm nbits=8 table, compartment chains
 ROWS, VOCAB = 256, 129_280          # decode batch x DeepSeek-V3 vocabulary
 TOKEN_K = 64                        # MH steps per token
 LATTICES, SIDE = 8, 128             # Gibbs lattice batch, 128x128 sites
+SCAN_SIDE = 1024                    # scan Gibbs lattice side (batch cell's)
 K = 32                              # steps per chunk (workload default)
 
 
@@ -186,3 +190,24 @@ def test_gibbs_fused_compiles(chip, model):
         ((LATTICES,), jnp.int32),
         *const_shapes,
     ))
+
+
+def test_scan_gibbs_job_draws_in_step(chip):
+    """The fused scan Gibbs job program makes each half-sweep one pass
+    over the lattice: the uniforms are drawn inside the step, so no
+    (chunk, B, H, W) operand exists and no float spin plane or roll
+    slice is written between steps.  Its temporaries stay under two
+    lattice planes (a chunk of uniforms alone would take 32)."""
+    engine = samplers.MHEngine(samplers.EngineConfig(
+        update="gibbs", randomness="fused", execution="scan",
+        chunk_steps=K, collect="last",
+    ))
+    shape = (LATTICES, SCAN_SIDE, SCAN_SIDE)
+    compiled = _submit_compiled.lower(
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=chip),
+        jax.ShapeDtypeStruct(shape, jnp.uint32, sharding=chip),
+        engine=engine, target=IsingModel(SCAN_SIDE, SCAN_SIDE),
+        n_steps=2 * K, chain_id=0, step0=0, collect=None, mesh=None,
+    ).compile()
+    plane = LATTICES * SCAN_SIDE * SCAN_SIDE * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * plane
