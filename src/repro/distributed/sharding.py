@@ -173,6 +173,27 @@ def spec_for(
     return P(*entries)
 
 
+def shard_over_chains(body, mesh, n: int):
+    """``body`` as a ``shard_map`` over the mesh axes the "chains" rule
+    gives an axis of ``n`` independent chains (or serving slots), every
+    argument and output split along its leading axis — or None where the
+    rule gives none: no mesh, or ``n`` not divisible by those axes (the
+    chains then run replicated rather than padded).  Chains never
+    communicate, so the sharded program is collective-free and
+    bit-identical to the unsharded one.  The caller jits it: run
+    eagerly, shard_map rejects an output the body builds from constants
+    alone (the empty ``samples`` of ``collect="last"``)."""
+    if mesh is None:
+        return None
+    spec = spec_for(("chains",), shape=(n,), mesh=mesh)
+    if spec is None or len(spec) == 0 or spec[0] is None:
+        return None
+    p = P(spec[0])
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=p, out_specs=p, check_vma=False
+    )
+
+
 def shard(x, logical_axes: Axes, rules: ShardingRules | None = None):
     """with_sharding_constraint by logical axes; no-op without a mesh."""
     spec = spec_for(logical_axes, rules or get_rules(), shape=jnp.shape(x))

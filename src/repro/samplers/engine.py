@@ -24,9 +24,16 @@ axes (DESIGN.md §2):
                       fused VMEM-resident kernel), with ``auto`` picking
                       by ``jax.default_backend()``
 
-For each update rule, the two executors consume identical randomness
-operands and mirror each other op-for-op, so with the same key they
-produce bit-identical sample streams (asserted in
+Each update rule has one executor per execution: a scan executor, and
+one Pallas executor (``run_pallas_mh`` / ``run_pallas_gibbs``) that
+launches the rule's kernels for solo runs, the chains axis and the
+serving tier's packed advance alike.  The Pallas executor works on the
+chain-folded layout (``fold_mh`` / ``fold_gibbs``, DESIGN.md
+§Chains-axis) and takes the C per-chain keys and a scalar or per-chain
+``step0``; a solo state is the C = 1 fold.  ``MHEngine.run`` is the one
+dispatch over them.  For each update rule, the two executors consume
+identical randomness operands and mirror each other op-for-op, so with
+the same key they produce bit-identical sample streams (asserted in
 tests/test_sampler_engine.py and tests/test_workloads.py).  Randomness
 streams in chunks of ``chunk_steps`` — operands for step ``t`` depend
 only on ``(key, step0 + t)`` — so chains of any length run in O(chunk)
@@ -60,7 +67,6 @@ from repro.samplers.randomness import (
     RandomnessBackend,
     chain_key,
     chain_keys,
-    draw_cache_size,
     make_randomness_backend,
 )
 from repro.samplers.targets import logits_target
@@ -359,19 +365,26 @@ def _run_scan(
     return samples, acc, words, logp
 
 
-def _step0_base(step0):
-    """Best-effort concrete step0 for the pallas executors.  The chunk
-    *schedule* (the python loop) is always static, but the absolute-step
-    base is a runtime operand of the fused kernels (and of the
-    checkerboard-parity argument), so a traced ``step0`` is fine for
-    collect="all"/"last" — successive serving segments and packed slots
-    reuse one compiled program.  Only thinning still needs a concrete
-    offset (the kept-slice stride is resolved at python level), which
-    ``_parse_collect`` enforces with a actionable error upstream."""
+def _step0_base(step0, extent: int):
+    """``(step0, base)`` for the pallas executors.  The chunk *schedule*
+    (the python loop) is always static, but the absolute-step base is a
+    runtime operand of the fused kernels (and of the checkerboard
+    parity), so a traced ``step0`` is fine for collect="all"/"last" —
+    successive serving segments and packed slots reuse one compiled
+    program.  ``step0`` comes back a python int where it is concrete (the
+    eager loop then adds chunk starts in python); ``base`` is what the
+    kernels read: ``step0`` itself, or — for a ``(C,)`` step0, one base
+    per chain — each chain's base repeated over its ``extent`` columns
+    or lattices, chain-major like the fold.  Only thinning needs a
+    concrete int (the kept-slice stride is resolved at python level),
+    which ``_parse_collect`` enforces with an actionable error upstream."""
+    if jnp.ndim(step0):
+        return step0, jnp.repeat(jnp.asarray(step0, jnp.int32), extent)
     try:
-        return int(step0)
+        step0 = int(step0)
     except TypeError:
-        return step0
+        pass
+    return step0, step0
 
 
 @functools.lru_cache(maxsize=None)
@@ -392,19 +405,41 @@ def _chunk_writer(ndim: int):
     return jax.jit(write, donate_argnums=(0,))
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1, 4, 5, 6, 7), inline=True)
+def _draw_folded(backend, fold, keys, t0, n_steps, shape, nbits, need_flips):
+    """The operand kernels' chunk draw (host/cim randomness): each of the
+    C chains' operands for steps [t0, t0 + n_steps) at its own key — and
+    its own base, where ``t0`` is ``(C,)`` — folded by the rule's
+    ``fold``.  One compiled program per static signature with the keys
+    and ``t0`` traced, so an eager chunk is one dispatch for any C; under
+    a trace ``inline=True`` splices the body into the caller's program.
+    At C = 1 the fold is the identity, and the one chain draws without a
+    vmap."""
+
+    def draw(k, t):
+        return backend.chunk(k, t, n_steps, shape, nbits, need_flips)
+
+    if keys.shape[0] == 1:
+        return draw(keys[0], jnp.reshape(t0, ()))
+    per_chain = jax.vmap(draw, in_axes=(0, 0 if jnp.ndim(t0) else None))(
+        keys, t0
+    )
+    return jax.tree.map(fold, per_chain)
+
+
 def _drive_pallas_chunks(
     run_chunk, init_state, n_steps, chunk, step0, collect, draw=None,
-    randomness=None, draw_cache=None,
+    randomness=None,
 ):
-    """THE fused-executor chunk scheduler — the python chunk loop all four
+    """THE fused-executor chunk scheduler — the python chunk loop both
     pallas executors share.
 
     ``run_chunk(state, start, n, *operands)`` launches one kernel program
     for relative steps [start, start + n) and returns (samples (n, *state
     shape) uint32, per-site count (*state shape) int32).  ``draw(start,
     n)`` returns the chunk's randomness operands for the operand kernels
-    (``randomness`` names the backend); fused randomness draws in the
-    kernel and passes no ``draw``.  Kept rows are
+    through ``_draw_folded`` (``randomness`` names the backend); fused
+    randomness draws in the kernel and passes no ``draw``.  Kept rows are
     written straight into one preallocated output buffer via
     ``lax.dynamic_update_slice``: under a trace (``run_engine`` or any
     caller-side jit — which also collapses the loop into a single
@@ -422,13 +457,10 @@ def _drive_pallas_chunks(
     records one ``engine.chunk`` span per chunk, holding a
     ``randomness.draw`` span around the operand draw and an
     ``engine.emit`` span around the kept-row write and the state/count
-    glue; the caller's ``engine.finish`` covers the job's tail.  Where
-    ``draw`` calls the compiled draw directly (the solo executors),
-    ``draw_cache`` reads its cache size, and each ``randomness.draw``
-    span carries ``jit_cache="miss"`` if the draw compiled, else
-    ``"hit"``; the chains executors call it under ``vmap``, whose
-    batched program JAX caches apart, so they pass none.  Under a trace
-    the loop is staged, not run, and records nothing.
+    glue; the caller's ``engine.finish`` covers the job's tail.  Each
+    ``randomness.draw`` span carries ``jit_cache="miss"`` if the draw
+    compiled, else ``"hit"``, for solo and chains runs alike.  Under a
+    trace the loop is staged, not run, and records nothing.
     """
     mode, k = collect
     chunk = _effective_chunk(n_steps, chunk, k if mode == "thin" else None)
@@ -442,8 +474,7 @@ def _drive_pallas_chunks(
         n_keep = 0
     traced = isinstance(state, jax.core.Tracer)
     span = _host_spans(traced)
-    if span is not telemetry.span:
-        draw_cache = None
+    draw_cache = _draw_folded._cache_size if span is telemetry.span else None
     out = jnp.zeros((n_keep, *state.shape), jnp.uint32)
     zeros = (0,) * state.ndim
     pos = 0
@@ -482,61 +513,116 @@ def _drive_pallas_chunks(
     return out, acc, state
 
 
+@functools.partial(jax.jit, static_argnums=(1,), inline=True)
 def _fused_key_cols(keys, repeat: int):
     """Per-column/lattice chain-key words for the fused kernels: the two
-    uint32 words of each chain key (kernels/rng), repeated over the
-    chain's folded extent — chain-major, matching the executors' fold
-    layout.  ``keys`` is one key or a stacked (C, ...) batch; this is
-    the ONLY randomness state the fused kernels receive (8 bytes per
-    column/lattice per chunk, replacing per-step operand planes)."""
+    uint32 words of each of the C stacked chain keys (kernels/rng), each
+    repeated over its chain's ``repeat`` columns or lattices —
+    chain-major, the fold's layout.  This is the ONLY randomness state
+    the fused kernels receive (8 bytes per column/lattice per chunk,
+    replacing per-step operand planes); one dispatch a job eagerly."""
     from repro.kernels import rng  # avoid import cycle
 
-    if getattr(keys, "ndim", 0) and not jnp.issubdtype(
-        keys.dtype, jax.dtypes.prng_key
-    ):
-        batched = keys.ndim > 1  # raw uint32 keys carry a trailing (2,)
-    else:
-        batched = getattr(keys, "ndim", 0) > 0
-    if batched:
-        kw = jax.vmap(lambda k: jnp.stack(rng.key_words(k)))(keys)
-        return (
-            jnp.repeat(kw[:, 0], repeat),
-            jnp.repeat(kw[:, 1], repeat),
-        )
-    k0, k1 = rng.key_words(keys)
-    return (
-        jnp.broadcast_to(k0, (repeat,)),
-        jnp.broadcast_to(k1, (repeat,)),
+    kw = jax.vmap(lambda k: jnp.stack(rng.key_words(k)))(keys)
+    return jnp.repeat(kw[:, 0], repeat), jnp.repeat(kw[:, 1], repeat)
+
+
+# --- the Pallas executors and the chains fold (DESIGN.md §Chains-axis) ------
+#
+# One executor per update rule launches that rule's kernels, for solo
+# runs, the chains axis and the serving tier's packed advance alike.  It
+# works on the *folded* layout: C independent chains ride the kernel's
+# batched grid — the compartment axis (mh, state (B, C·Cc), grid
+# (B, C·Cc/BLOCK_C)) or the lattice-batch axis (gibbs, state (C·B, H, W),
+# grid (C·B,)) — chain-major, so chain c owns one contiguous block.  Both
+# grids block over exactly the folded axis and every op is per-column /
+# per-lattice, so folding preserves bit-parity; a solo state is its own
+# C = 1 fold.  Per-chain randomness is counter-derived — chain c streams
+# from fold_in(key, c), then per-step fold_in(·, t) — so chain c of a
+# C-chain run is bit-identical to a solo run with chain_id=c.
+
+
+def fold_mh(x):
+    """(C, ..., B, Cc) -> (..., B, C·Cc): chains ride the MH compartment
+    axis, chain c owning columns [c·Cc, (c+1)·Cc).  States, kept samples
+    and operand chunks fold alike."""
+    x = jnp.moveaxis(x, 0, -2)
+    return x.reshape(*x.shape[:-2], x.shape[-2] * x.shape[-1])
+
+
+def unfold_mh(x, num_chains: int):
+    """(..., B, C·Cc) -> (C, ..., B, Cc): the inverse of ``fold_mh``."""
+    x = x.reshape(*x.shape[:-1], num_chains, x.shape[-1] // num_chains)
+    return jnp.moveaxis(x, -2, 0)
+
+
+def fold_gibbs(x):
+    """(C, ..., B, H, W) -> (..., C·B, H, W): chains ride the Gibbs
+    lattice-batch axis, chain c owning lattices [c·B, (c+1)·B)."""
+    x = jnp.moveaxis(x, 0, -4)
+    return x.reshape(
+        *x.shape[:-4], x.shape[-4] * x.shape[-3], *x.shape[-2:]
     )
 
 
-def _run_pallas(
-    key, target, backend, nbits, n_steps, chunk, step0, block_c, init_words,
-    collect,
+def unfold_gibbs(x, num_chains: int):
+    """(..., C·B, H, W) -> (C, ..., B, H, W): the inverse of
+    ``fold_gibbs``."""
+    x = x.reshape(
+        *x.shape[:-3], num_chains, x.shape[-3] // num_chains, *x.shape[-2:]
+    )
+    return jnp.moveaxis(x, -4, 0)
+
+
+def _fused_gibbs_logit(target):
+    """(logit_fn, consts) for the fused kernel: models whose conditional
+    closes over array parameters expose them as ``fused_consts`` plus a
+    ``fused_logit(state, *consts)`` sharing the scan-side math body —
+    kernel traces cannot capture array closures (DESIGN.md §Tempering)."""
+    consts = tuple(getattr(target, "fused_consts", ()) or ())
+    if consts:
+        return target.fused_logit, consts
+    return target.conditional_logit, ()
+
+
+def run_pallas_mh(
+    engine, keys, target, state, n_steps: int, step0, collect, chunk=None
 ):
+    """THE Pallas MH executor: solo runs, the chains axis and the serving
+    tier's packed advance launch the MH kernels only through here.
+
+    ``state`` is C chains' folded (B, C·Cc) state (``fold_mh``; a solo
+    (B, Cc) state is its own fold), ``keys`` the C stacked per-chain
+    keys, already derived (``chain_key``), and ``step0`` a scalar or a
+    ``(C,)`` vector of per-chain absolute-step bases — the vector only
+    under collect "all" or "last", whose kept set does not depend on it.
+    ``collect`` is a parsed ``(mode, k)`` (``parse_collect``) and
+    ``chunk`` the steps one kernel call runs (default: the engine's
+    ``chunk_steps``).  Returns folded ``(samples, accept_count,
+    final_words)``; ``final_logp`` gives the final log-prob."""
     from repro.kernels.mh import ops as mh_ops  # avoid import cycle
 
-    if init_words.ndim != 2:
-        raise IneligibleExecution(
-            f"pallas execution expects (B, C) chain state, got {init_words.shape}"
-        )
-    step0 = _step0_base(step0)
+    backend, block_c, nbits = (
+        engine.randomness, engine.config.block_c, target.nbits
+    )
+    b, cc = state.shape[0], state.shape[1] // keys.shape[0]
+    step0, base = _step0_base(step0, cc)
 
     draw = None
     if backend.name == "fused":
-        c = init_words.shape[1]
-        k0c, k1c = _fused_key_cols(key, c)
+        k0c, k1c = _fused_key_cols(keys, cc)
 
         def run_chunk(state, start, n):
             return mh_ops.mh_sample_fused(
-                target.table, state, k0c, k1c, n_steps=n, t0=step0 + start,
-                nbits=nbits, p_bfr=backend.p_bfr, cc=c, block_c=block_c,
+                target.table, state, k0c, k1c, n_steps=n, t0=base + start,
+                nbits=nbits, p_bfr=backend.p_bfr, cc=cc, block_c=block_c,
             )
     else:
 
         def draw(start, n):
-            return backend.chunk(
-                key, step0 + start, n, init_words.shape, nbits
+            return _draw_folded(
+                backend, fold_mh, keys, step0 + start, n, (b, cc), nbits,
+                True,
             )
 
         def run_chunk(state, start, n, flips, u):
@@ -545,9 +631,63 @@ def _run_pallas(
             )
 
     return _drive_pallas_chunks(
-        run_chunk, init_words.astype(jnp.uint32), n_steps, chunk, step0,
-        collect, draw, backend.name, draw_cache_size,
+        run_chunk, state.astype(jnp.uint32), n_steps,
+        chunk or engine.config.chunk_steps, step0, collect, draw,
+        backend.name,
     )
+
+
+def run_pallas_gibbs(
+    engine, keys, target, state, n_steps: int, step0, collect, chunk=None
+):
+    """THE Pallas Gibbs executor: as ``run_pallas_mh``, over C chains'
+    folded (C·B, H, W) lattice state (``fold_gibbs``; a solo (B, H, W)
+    state is its own fold).  The absolute-step base also carries each
+    lattice's checkerboard parity.  Returns folded ``(samples,
+    flip_count, final_words)``."""
+    from repro.kernels.gibbs import ops as gibbs_ops  # avoid import cycle
+
+    backend = engine.randomness
+    lat_b = state.shape[0] // keys.shape[0]
+    step0, base = _step0_base(step0, lat_b)
+    logit_fn, consts = _fused_gibbs_logit(target)
+
+    draw = None
+    if backend.name == "fused":
+        k0b, k1b = _fused_key_cols(keys, lat_b)
+
+        def run_chunk(state, start, n):
+            return gibbs_ops.gibbs_sweep_fused(
+                state, k0b, k1b, logit_fn, n_steps=n, t0=base + start,
+                lat_b=lat_b, consts=consts,
+            )
+    else:
+
+        def draw(start, n):
+            return _draw_folded(
+                backend, fold_gibbs, keys, step0 + start, n,
+                (lat_b, *state.shape[1:]), 1, False,
+            )
+
+        def run_chunk(state, start, n, _flips, u):
+            return gibbs_ops.gibbs_sweep(
+                state, u, logit_fn, parity0=(base + start) % 2,
+                consts=consts,
+            )
+
+    return _drive_pallas_chunks(
+        run_chunk, state.astype(jnp.uint32), n_steps,
+        chunk or engine.config.chunk_steps, step0, collect, draw,
+        backend.name,
+    )
+
+
+# each rule's Pallas executor and its fold: what ``MHEngine.run`` and the
+# serving tier's packed advance look up
+PALLAS_EXECUTORS = {
+    "mh": (run_pallas_mh, fold_mh, unfold_mh),
+    "gibbs": (run_pallas_gibbs, fold_gibbs, unfold_gibbs),
+}
 
 
 def _count_step_draws(engine, target, n_steps: int) -> None:
@@ -608,189 +748,6 @@ def _run_scan_gibbs(
     return samples, acc, state
 
 
-def _run_pallas_gibbs(
-    key, target, backend, n_steps, chunk, step0, init_words, collect
-):
-    from repro.kernels.gibbs import ops as gibbs_ops  # avoid import cycle
-
-    if init_words.ndim != 3:
-        raise IneligibleExecution(
-            f"pallas Gibbs expects (B, H, W) lattice state, got "
-            f"{init_words.shape}"
-        )
-    step0 = _step0_base(step0)
-    logit_fn, consts = _fused_gibbs_logit(target)
-
-    draw = None
-    if backend.name == "fused":
-        b = init_words.shape[0]
-        k0b, k1b = _fused_key_cols(key, b)
-
-        def run_chunk(state, start, n):
-            return gibbs_ops.gibbs_sweep_fused(
-                state, k0b, k1b, logit_fn, n_steps=n, t0=step0 + start,
-                lat_b=b, consts=consts,
-            )
-    else:
-
-        def draw(start, n):
-            _, u = backend.chunk(
-                key, step0 + start, n, init_words.shape, 1, need_flips=False
-            )
-            return (u,)
-
-        def run_chunk(state, start, n, u):
-            return gibbs_ops.gibbs_sweep(
-                state, u, logit_fn, parity0=(step0 + start) % 2, consts=consts
-            )
-
-    return _drive_pallas_chunks(
-        run_chunk, init_words.astype(jnp.uint32), n_steps, chunk, step0,
-        collect, draw, backend.name, draw_cache_size,
-    )
-
-
-# --- chains axis (DESIGN.md §Chains-axis) ----------------------------------
-#
-# C independent chains run in ONE device program.  Per-chain randomness is
-# counter-derived — chain c streams from fold_in(key, c), then per-step
-# fold_in(·, t) — so chain c of a C-chain run is bit-identical to a solo
-# run with chain_id=c.  The scan executor vmaps over the chain axis; the
-# fused Pallas kernels get a *batched grid*: chains fold into the
-# compartment axis (mh, grid (B, C·Cc/BLOCK_C)) or the lattice-batch axis
-# (gibbs, grid (C·B,)) — both grids block over exactly the folded axis, and
-# every op is per-column/per-lattice, so folding preserves bit-parity.
-
-
-def _chains_fold_mh(x):
-    """(C, K, B, Cc) operands -> (K, B, C*Cc): chains ride the compartment
-    axis, chain-major blocks so chain c owns columns [c*Cc, (c+1)*Cc)."""
-    c, k, b, cc = x.shape
-    return jnp.transpose(x, (1, 2, 0, 3)).reshape(k, b, c * cc)
-
-
-def _run_pallas_chains(
-    keys, target, backend, nbits, n_steps, chunk, step0, block_c, init,
-    collect,
-):
-    """Fused MH over C chains: one batched-grid kernel program per chunk."""
-    from repro.kernels.mh import ops as mh_ops  # avoid import cycle
-
-    if init.ndim != 3:
-        raise IneligibleExecution(
-            f"multi-chain pallas execution expects (num_chains, B, C) chain "
-            f"state, got {init.shape}"
-        )
-    step0 = _step0_base(step0)
-    c_chains, b, cc = init.shape
-    state0 = jnp.transpose(init.astype(jnp.uint32), (1, 0, 2)).reshape(
-        b, c_chains * cc
-    )
-
-    draw = None
-    if backend.name == "fused":
-        k0c, k1c = _fused_key_cols(keys, cc)  # chain-major: matches fold
-
-        def run_chunk(state, start, n):
-            return mh_ops.mh_sample_fused(
-                target.table, state, k0c, k1c, n_steps=n, t0=step0 + start,
-                nbits=nbits, p_bfr=backend.p_bfr, cc=cc, block_c=block_c,
-            )
-    else:
-
-        def draw(start, n):
-            flips, u = jax.vmap(
-                lambda k: backend.chunk(k, step0 + start, n, (b, cc), nbits)
-            )(keys)
-            return _chains_fold_mh(flips), _chains_fold_mh(u)
-
-        def run_chunk(state, start, n, flips, u):
-            return mh_ops.mh_sample(
-                target.table, state, flips, u, nbits=nbits, block_c=block_c,
-            )
-
-    samples, acc, state = _drive_pallas_chunks(
-        run_chunk, state0, n_steps, chunk, step0, collect, draw, backend.name
-    )
-
-    def unfold(x):  # (..., B, C*Cc) -> (C, ..., B, Cc)
-        lead = x.shape[:-2]
-        x = x.reshape(*lead, b, c_chains, cc)
-        return jnp.moveaxis(x, -2, 0)
-
-    logp = target.log_prob(state).astype(jnp.float32)
-    return unfold(samples), unfold(acc), unfold(state), unfold(logp)
-
-
-def _fused_gibbs_logit(target):
-    """(logit_fn, consts) for the fused kernel: models whose conditional
-    closes over array parameters expose them as ``fused_consts`` plus a
-    ``fused_logit(state, *consts)`` sharing the scan-side math body —
-    kernel traces cannot capture array closures (DESIGN.md §Tempering)."""
-    consts = tuple(getattr(target, "fused_consts", ()) or ())
-    if consts:
-        return target.fused_logit, consts
-    return target.conditional_logit, ()
-
-
-def _run_pallas_gibbs_chains(
-    keys, target, backend, n_steps, chunk, step0, init, collect
-):
-    """Fused checkerboard Gibbs over C chains: chains fold into the
-    lattice-batch grid axis."""
-    from repro.kernels.gibbs import ops as gibbs_ops  # avoid import cycle
-
-    if init.ndim != 4:
-        raise IneligibleExecution(
-            f"multi-chain pallas Gibbs expects (num_chains, B, H, W) lattice "
-            f"state, got {init.shape}"
-        )
-    step0 = _step0_base(step0)
-    logit_fn, consts = _fused_gibbs_logit(target)
-    c_chains, b, h, w = init.shape
-    state0 = init.astype(jnp.uint32).reshape(c_chains * b, h, w)
-
-    draw = None
-    if backend.name == "fused":
-        k0b, k1b = _fused_key_cols(keys, b)  # chain-major: matches fold
-
-        def run_chunk(state, start, n):
-            return gibbs_ops.gibbs_sweep_fused(
-                state, k0b, k1b, logit_fn, n_steps=n, t0=step0 + start,
-                lat_b=b, consts=consts,
-            )
-    else:
-
-        def draw(start, n):
-            u = jax.vmap(
-                lambda k: backend.chunk(
-                    k, step0 + start, n, (b, h, w), 1, need_flips=False
-                )[1]
-            )(keys)
-            return (
-                jnp.transpose(u, (1, 0, 2, 3, 4)).reshape(
-                    n, c_chains * b, h, w
-                ),
-            )
-
-        def run_chunk(state, start, n, u_fold):
-            return gibbs_ops.gibbs_sweep(
-                state, u_fold, logit_fn, parity0=(step0 + start) % 2,
-                consts=consts,
-            )
-
-    samples, acc, state = _drive_pallas_chunks(
-        run_chunk, state0, n_steps, chunk, step0, collect, draw, backend.name
-    )
-
-    def unfold(x):  # (..., C*B, H, W) -> (C, ..., B, H, W)
-        lead = x.shape[:-3]
-        x = x.reshape(*lead, c_chains, b, h, w)
-        return jnp.moveaxis(x, len(lead), 0)
-
-    return unfold(samples), unfold(acc), unfold(state)
-
-
 def _gibbs_logp(target, words):
     """Per-site conditional log-prob (pseudo-likelihood) of a Gibbs
     state: its ``final_logp``."""
@@ -798,6 +755,20 @@ def _gibbs_logp(target, words):
     return jnp.where(
         words == 1, jax.nn.log_sigmoid(logit), jax.nn.log_sigmoid(-logit)
     ).astype(jnp.float32)
+
+
+def final_logp(update: str, target, words):
+    """The ``final_logp`` a run reports for its final ``words``: the
+    target's log-prob under ``mh`` (table targets read (B, C) words, so
+    leading chain or slot axes are mapped over) and the per-site
+    pseudo-likelihood under ``gibbs`` — the one place each is derived
+    (the scan MH executor carries its own)."""
+    if update == "gibbs":
+        return _gibbs_logp(target, words)
+    log_prob = target.log_prob
+    for _ in range(words.ndim - 2):
+        log_prob = jax.vmap(log_prob)
+    return log_prob(words).astype(jnp.float32)
 
 
 def _result(samples, acc, words, logp, n_steps: int, size: int):
@@ -811,35 +782,6 @@ def _result(samples, acc, words, logp, n_steps: int, size: int):
         final_logp=logp,
         n_steps=jnp.int32(n_steps),
     )
-
-
-def _shard_over_chains(body, mesh, num_chains: int, n_out: int):
-    """Wrap ``body(keys, init)`` in shard_map over the mesh's chains axes.
-
-    The "chains" logical axis resolves through the standard sharding-rules
-    table (distributed/sharding.py), including the divisibility filter — a
-    chain count the mesh doesn't divide runs replicated (unsharded) rather
-    than padded, and a mesh-less call is the identity.  Chains never
-    communicate, so the sharded program is collective-free and
-    bit-identical to the unsharded one.  The wrapper is jitted: run
-    eagerly, shard_map rejects an output the body builds from constants
-    alone (the empty ``samples`` of ``collect="last"``).
-    """
-    if mesh is None:
-        return body
-    from repro.distributed import sharding
-
-    spec = sharding.spec_for(("chains",), shape=(num_chains,), mesh=mesh)
-    if spec is None or len(spec) == 0 or spec[0] is None:
-        return body
-    p = jax.sharding.PartitionSpec(spec[0])
-    return jax.jit(jax.shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(p, p),
-        out_specs=tuple(p for _ in range(n_out)),
-        check_vma=False,
-    ))
 
 
 class MHEngine:
@@ -944,50 +886,91 @@ class MHEngine:
         if isinstance(step0, int) and step0 < 0:
             raise ValueError(f"step0 must be >= 0, got {step0}")
         collect = self._parse_collect(collect, step0)
+        cfg, backend = self.config, self._backend
+        n_chains, mh = cfg.num_chains, cfg.update == "mh"
+        if not mh and not hasattr(target, "conditional_logit"):
+            raise ValueError(
+                "gibbs update needs a conditional target exposing "
+                "conditional_logit/update_mask (e.g. workloads.ising."
+                f"IsingModel); got {type(target).__name__}"
+            )
+        execution = resolve_execution(cfg.execution, target, cfg.update)
         if init_logp is not None and (
-            self.config.num_chains > 1 or self.config.update == "gibbs"
+            n_chains > 1 or not mh or execution != "scan"
         ):
             raise ValueError(
-                "init_logp resumes the solo MH carry only — the Gibbs "
-                "carry holds no log-prob and the chains axis derives its "
-                "own per-chain carries"
+                "init_logp resumes the solo MH scan carry only — the Gibbs "
+                "carry holds no log-prob, the chains axis derives its own "
+                "per-chain carries, and the pallas MH kernel re-derives the "
+                "log-prob from the state words"
             )
         _count_step_draws(self, target, n_steps)
-        if self.config.num_chains > 1:
-            return self._run_chains(
-                key, target, n_steps, init_words, mesh, base=chain_id,
-                step0=step0, collect=collect,
+        init = jnp.asarray(init_words)
+        # the leading axis is ALWAYS the chain axis — never guessed from
+        # shape coincidences (a solo init whose first dim happens to equal
+        # num_chains would be silently misread); broadcast explicitly
+        if n_chains > 1 and (init.ndim == 0 or init.shape[0] != n_chains):
+            raise ValueError(
+                f"multi-chain init_words must carry a leading "
+                f"(num_chains={n_chains},) axis, got {init.shape}; "
+                f"broadcast a solo init with "
+                f"jnp.broadcast_to(init, ({n_chains}, *init.shape))"
             )
-        key = chain_key(key, chain_id)
-        if self.config.update == "gibbs":
-            return self._run_gibbs(
-                key, target, n_steps, init_words, step0, collect
-            )
-        execution = resolve_execution(self.config.execution, target)
-        args = (key, target, self._backend, target.nbits, n_steps,
-                self.config.chunk_steps, step0)
+        # body(key(s), state) -> (samples, acc, words, logp or None)
         if execution == "scan":
-            samples, acc, words, logp = _run_scan(
-                *args, init_words, collect, init_logp
+
+            def body(k, w):  # one chain; C > 1 vmaps it
+                if mh:
+                    return _run_scan(
+                        k, target, backend, target.nbits, n_steps,
+                        cfg.chunk_steps, step0, w, collect, init_logp,
+                    )
+                return (*_run_scan_gibbs(
+                    k, target, backend, n_steps, cfg.chunk_steps, step0, w,
+                    collect,
+                ), None)
+        else:
+            lead = "(num_chains, " if n_chains > 1 else "("
+            layout = lead + ("B, C) chain" if mh else "B, H, W) lattice")
+            if init.ndim != (2 if mh else 3) + (n_chains > 1):
+                raise IneligibleExecution(
+                    f"pallas execution expects {layout} state, got "
+                    f"{init.shape}"
+                )
+            run_pallas, fold, unfold = PALLAS_EXECUTORS[cfg.update]
+
+            def body(ks, w):  # C chains at once, through the fold
+                if n_chains == 1:  # a solo state is its own fold
+                    out = run_pallas(self, ks, target, w, n_steps, step0,
+                                     collect)
+                else:
+                    out = tuple(unfold(x, ks.shape[0]) for x in run_pallas(
+                        self, ks, target, fold(w), n_steps, step0, collect
+                    ))
+                return (*out, None)
+
+        if n_chains == 1:
+            k = chain_key(key, chain_id)
+            samples, acc, words, logp = body(
+                k if execution == "scan" else k[None], init
             )
         else:
-            if init_logp is not None:
-                raise ValueError(
-                    "init_logp needs scan execution — the pallas MH kernel "
-                    "re-derives the table log-prob from the state words"
-                )
-            samples, acc, words = _run_pallas(
-                *args, self.config.block_c, init_words, collect
+            if execution == "scan":
+                body = jax.vmap(body)
+            from repro.distributed.sharding import shard_over_chains
+
+            sharded = shard_over_chains(body, mesh, n_chains)
+            if sharded is not None:
+                body = jax.jit(sharded)
+            samples, acc, words, logp = body(
+                chain_keys(key, n_chains, base=chain_id), init
             )
-            logp = None
-        # the job's tail: the pallas kernel carries no log-prob, so it is
-        # re-derived from the final state
+        # the job's tail: the final log-prob is re-derived from the final
+        # state wherever the executor carries none
         with _host_spans()("engine.finish"):
             if logp is None:
-                logp = target.log_prob(words).astype(jnp.float32)
-            return _result(
-                samples, acc, words, logp, n_steps, init_words.size
-            )
+                logp = final_logp(cfg.update, target, words)
+            return _result(samples, acc, words, logp, n_steps, init.size)
 
     def _parse_collect(self, collect: str | None, step0) -> tuple[str, int]:
         """Resolve the run-level override against the config default and
@@ -1012,107 +995,6 @@ class MHEngine:
                     "collect='last' also accepts traced offsets."
                 ) from e
         return mode_k
-
-    def _run_gibbs(
-        self, key, target, n_steps: int, init_words, step0, collect
-    ) -> EngineResult:
-        if not hasattr(target, "conditional_logit"):
-            raise ValueError(
-                "gibbs update needs a conditional target exposing "
-                "conditional_logit/update_mask (e.g. workloads.ising."
-                f"IsingModel); got {type(target).__name__}"
-            )
-        execution = resolve_execution(self.config.execution, target, "gibbs")
-        args = (key, target, self._backend, n_steps, self.config.chunk_steps,
-                step0)
-        if execution == "scan":
-            samples, acc, words = _run_scan_gibbs(*args, init_words, collect)
-        else:
-            samples, acc, words = _run_pallas_gibbs(*args, init_words, collect)
-        with _host_spans()("engine.finish"):
-            logp = _gibbs_logp(target, words)
-            return _result(
-                samples, acc, words, logp, n_steps, init_words.size
-            )
-
-    def _run_chains(
-        self, key, target, n_steps: int, init_words, mesh, base: int = 0,
-        step0=0, collect: tuple[str, int] = ("all", 1),
-    ):
-        """C independent chains in one device program (optionally sharded).
-
-        ``base`` offsets the chain ids: the run covers chains
-        [base, base + C), so two C-chain runs with bases 0 and C compose
-        into exactly the 2C-chain run's streams.
-        """
-        cfg = self.config
-        num_chains = cfg.num_chains
-        init = jnp.asarray(init_words)
-        # the leading axis is ALWAYS the chain axis — never guessed from
-        # shape coincidences (a solo init whose first dim happens to equal
-        # num_chains would be silently misread); broadcast explicitly
-        if init.ndim == 0 or init.shape[0] != num_chains:
-            raise ValueError(
-                f"multi-chain init_words must carry a leading "
-                f"(num_chains={num_chains},) axis, got {init.shape}; "
-                f"broadcast a solo init with "
-                f"jnp.broadcast_to(init, ({num_chains}, *init.shape))"
-            )
-        keys = chain_keys(key, num_chains, base=base)
-        if cfg.update == "gibbs":
-            if not hasattr(target, "conditional_logit"):
-                raise ValueError(
-                    "gibbs update needs a conditional target exposing "
-                    "conditional_logit/update_mask (e.g. workloads.ising."
-                    f"IsingModel); got {type(target).__name__}"
-                )
-            execution = resolve_execution(cfg.execution, target, "gibbs")
-            if execution == "scan":
-
-                def body(ks, ini):
-                    return jax.vmap(
-                        lambda k, w: _run_scan_gibbs(
-                            k, target, self._backend, n_steps,
-                            cfg.chunk_steps, step0, w, collect,
-                        )
-                    )(ks, ini)
-            else:
-
-                def body(ks, ini):
-                    return _run_pallas_gibbs_chains(
-                        ks, target, self._backend, n_steps, cfg.chunk_steps,
-                        step0, ini, collect,
-                    )
-
-            body = _shard_over_chains(body, mesh, num_chains, 3)
-            samples, acc, words = body(keys, init)
-            logp = None
-        else:
-            execution = resolve_execution(cfg.execution, target)
-            nbits = target.nbits
-            if execution == "scan":
-
-                def body(ks, ini):
-                    return jax.vmap(
-                        lambda k, w: _run_scan(
-                            k, target, self._backend, nbits, n_steps,
-                            cfg.chunk_steps, step0, w, collect,
-                        )
-                    )(ks, ini)
-            else:
-
-                def body(ks, ini):
-                    return _run_pallas_chains(
-                        ks, target, self._backend, nbits, n_steps,
-                        cfg.chunk_steps, step0, cfg.block_c, ini, collect,
-                    )
-
-            body = _shard_over_chains(body, mesh, num_chains, 4)
-            samples, acc, words, logp = body(keys, init)
-        with _host_spans()("engine.finish"):
-            if logp is None:
-                logp = _gibbs_logp(target, words)
-            return _result(samples, acc, words, logp, n_steps, init.size)
 
     def sample_tokens(
         self,

@@ -15,12 +15,13 @@ Three pieces:
     ``mesh``, the slot axis is sharded via the standard "chains"
     sharding rule (slots, like chains, never communicate — the sharded
     program is collective-free and bit-identical).
-  * ``make_pallas_advance_fn`` is the pallas-execution edition: all
-    slots fold into ONE batched fused-kernel grid (the §Chains-axis
-    fold, with per-slot keys and per-slot operand ``step0`` — the fused
-    kernels take the absolute-step base as a runtime operand, so
-    heterogeneous slot offsets share one compiled kernel).  This
-    replaces the historical per-slot solo-submit fallback.
+  * ``make_pallas_advance_fn`` is the pallas-execution edition: it
+    calls the engine's Pallas executor for the rule once over all slots,
+    folded with the engine's §Chains-axis fold helpers, slots in place
+    of chains, with per-slot keys and a per-slot ``step0`` (the kernels
+    take the absolute-step base as a runtime operand, so heterogeneous
+    slot offsets share one compiled kernel).  It launches no kernel of
+    its own.
   * ``SegmentPipeline`` bounds how far host-side finalisation may lag
     the device.  The executor pushes one finalize thunk per segment
     (with all needed device slices already enqueued); the pipeline runs
@@ -45,12 +46,10 @@ import jax
 import jax.numpy as jnp
 
 from repro import telemetry
+from repro.distributed.sharding import shard_over_chains
 from repro.samplers import RunPlan
-from repro.samplers.engine import (
-    _chains_fold_mh,
-    _fused_gibbs_logit,
-    _fused_key_cols,
-)
+from repro.samplers.engine import PALLAS_EXECUTORS, final_logp, parse_collect
+from repro.samplers.randomness import chain_key
 
 
 def poison_donated(*arrays) -> None:
@@ -76,31 +75,6 @@ def poison_donated(*arrays) -> None:
                 delete()
         except RuntimeError:  # pragma: no cover - committed/tracer buffers
             pass
-
-
-def _slot_axis_wrap(mesh, n_slots: int, n_in: int, n_out: int):
-    """shard_map wrapper over the slot axis, or identity without a mesh.
-
-    Slots resolve through the "chains" sharding rule (they are the same
-    kind of axis: independent, never communicating), including the
-    divisibility filter — a slot count the mesh doesn't divide runs
-    replicated rather than padded.
-    """
-    if mesh is None:
-        return lambda body: body
-    from repro.distributed import sharding
-
-    spec = sharding.spec_for(("chains",), shape=(n_slots,), mesh=mesh)
-    if spec is None or len(spec) == 0 or spec[0] is None:
-        return lambda body: body
-    p = jax.sharding.PartitionSpec(spec[0])
-    return lambda body: jax.shard_map(
-        body,
-        mesh=mesh,
-        in_specs=tuple(p for _ in range(n_in)),
-        out_specs=tuple(p for _ in range(n_out)),
-        check_vma=False,
-    )
 
 
 def make_class_advance_fn(members, n_pad: int, n_slots: int, mesh=None):
@@ -161,7 +135,6 @@ def make_class_advance_fn(members, n_pad: int, n_slots: int, mesh=None):
         return branch
 
     branches = [make_branch(m) for m in members]
-    wrap = _slot_axis_wrap(mesh, n_slots, n_in=5, n_out=4)
 
     @partial(
         jax.jit, static_argnames=("seg", "collect"), donate_argnums=(0, 1)
@@ -176,137 +149,51 @@ def make_class_advance_fn(members, n_pad: int, n_slots: int, mesh=None):
                 return bound[0](w, lp, k, s0)
             return jax.lax.switch(ti, bound, w, lp, k, s0)
 
-        def body(w, lp, k, s0, ti):
-            return jax.vmap(one)(w, lp, k, s0, ti)
-
-        return wrap(body)(words, logp, keys, step0s, tidx)
+        body = jax.vmap(one)
+        body = shard_over_chains(body, mesh, n_slots) or body
+        return body(words, logp, keys, step0s, tidx)
 
     return advance
 
 
 def make_pallas_advance_fn(engine, target, state_shape: tuple):
-    """The packed pallas-segment program: one batched fused-kernel grid
-    over ALL slots (no per-slot fallback).
+    """The packed pallas-segment program: all slots advance in ONE call
+    of the engine's Pallas executor for the rule (``run_pallas_mh`` /
+    ``run_pallas_gibbs``), with slots in place of chains.
 
     Returns ``advance(words, keys, step0s, *, seg, collect)`` ->
     ``(samples, words', logp', accept)``, each with a leading slot axis
-    and the member's *shaped* state (pallas kernel geometry is per
-    workload, so a pallas executor is a single-member class).  The fold
-    is exactly the §Chains-axis fold with slots in place of chains —
-    slot-major into the MH compartment axis (site = i·C + c stays the
-    solo site index) or the Gibbs lattice-batch axis (i mod B stays the
-    solo lattice index) — and the fused kernels take per-column /
-    per-lattice key words AND the absolute-step base ``step0`` as
-    runtime operands, so heterogeneous slot offsets (mid-flight joins)
-    share one compiled program and every slot advances on its solo
-    stream bit-for-bit.  Host/cim randomness ships per-slot operand
-    chunks drawn at each slot's own offset instead.
+    and the member's *shaped* ``state_shape`` per slot (pallas kernel
+    geometry is per workload, so a pallas executor is a single-member
+    class).  The advance derives each slot's stream key as a solo run
+    does (``chain_key(key, 0)``), folds the slots with the rule's
+    §Chains-axis fold, runs the executor with one chunk of ``seg`` steps
+    (one kernel call a segment) and the per-slot absolute-step bases
+    ``step0s`` — a runtime operand, so heterogeneous slot offsets
+    (mid-flight joins) share one compiled program and every slot
+    advances on its solo stream bit-for-bit — and unfolds.  ``collect``
+    is "all" or "last", the two modes a per-slot base allows.
 
-    ``words`` is donated; MH re-derives the final log-prob from the
-    table and Gibbs returns the final per-site conditional log-prob, so
-    no logp carry crosses segments on this path.
+    ``words`` is donated; the final log-prob is the engine's
+    ``final_logp`` of the final state, so no logp carry crosses
+    segments on this path.
     """
-    from repro.samplers.randomness import chain_key
-
-    backend = engine.randomness
     update = engine.config.update
-    block_c = engine.config.block_c
+    run_pallas, fold, unfold = PALLAS_EXECUTORS[update]
 
-    def _slot_chain_keys(keys):
-        # engine.run derives every stream from chain_key(key, chain_id=0)
-        # before touching the executors — replay that fold per slot so
-        # the packed kernels read the exact solo streams
-        return jax.vmap(lambda k: chain_key(k, 0))(keys)
-
-    if update == "mh":
-        from repro.kernels.mh import ops as mh_ops
-
-        nbits = target.nbits
-        b, c = state_shape
-
-        @partial(
-            jax.jit, static_argnames=("seg", "collect"), donate_argnums=(0,)
-        )
-        def advance(words, keys, step0s, *, seg, collect):
-            s = words.shape[0]
-            keys = _slot_chain_keys(keys)
-            state0 = jnp.transpose(words, (1, 0, 2)).reshape(b, s * c)
-            if backend.name == "fused":
-                k0c, k1c = _fused_key_cols(keys, c)
-                t0c = jnp.repeat(step0s.astype(jnp.int32), c)
-                samples, acc = mh_ops.mh_sample_fused(
-                    target.table, state0, k0c, k1c, n_steps=seg, t0=t0c,
-                    nbits=nbits, p_bfr=backend.p_bfr, cc=c, block_c=block_c,
-                )
-            else:
-                flips, u = jax.vmap(
-                    lambda k, s0: backend.chunk(k, s0, seg, (b, c), nbits)
-                )(keys, step0s)
-                samples, acc = mh_ops.mh_sample(
-                    target.table, state0, _chains_fold_mh(flips),
-                    _chains_fold_mh(u), nbits=nbits, block_c=block_c,
-                )
-            # (seg, b, s*c) -> (s, seg, b, c); slot-major columns
-            samples = jnp.moveaxis(samples.reshape(seg, b, s, c), 2, 0)
-            acc = jnp.moveaxis(acc.reshape(b, s, c), 1, 0)
-            words_out = samples[:, -1]
-            logp = jax.vmap(
-                lambda w: target.log_prob(w).astype(jnp.float32)
-            )(words_out)
-            if collect != "all":
-                samples = samples[:, :0]
-            return samples, words_out, logp, acc
-
-    else:
-        from repro.kernels.gibbs import ops as gibbs_ops
-
-        logit_fn, consts = _fused_gibbs_logit(target)
-        b, h, w = state_shape
-
-        @partial(
-            jax.jit, static_argnames=("seg", "collect"), donate_argnums=(0,)
-        )
-        def advance(words, keys, step0s, *, seg, collect):
-            s = words.shape[0]
-            keys = _slot_chain_keys(keys)
-            state0 = words.reshape(s * b, h, w)
-            if backend.name == "fused":
-                k0b, k1b = _fused_key_cols(keys, b)
-                t0b = jnp.repeat(step0s.astype(jnp.int32), b)
-                samples, acc = gibbs_ops.gibbs_sweep_fused(
-                    state0, k0b, k1b, logit_fn, n_steps=seg, t0=t0b,
-                    lat_b=b, consts=consts,
-                )
-            else:
-                u = jax.vmap(
-                    lambda k, s0: backend.chunk(
-                        k, s0, seg, (b, h, w), 1, need_flips=False
-                    )[1]
-                )(keys, step0s)
-                u_fold = jnp.transpose(u, (1, 0, 2, 3, 4)).reshape(
-                    seg, s * b, h, w
-                )
-                samples, acc = gibbs_ops.gibbs_sweep(
-                    state0, u_fold, logit_fn,
-                    parity0=jnp.repeat(step0s.astype(jnp.int32) % 2, b),
-                    consts=consts,
-                )
-            # (seg, s*b, h, w) -> (s, seg, b, h, w); slot-major lattices
-            samples = jnp.moveaxis(
-                samples.reshape(seg, s, b, h, w), 1, 0
+    @partial(
+        jax.jit, static_argnames=("seg", "collect"), donate_argnums=(0,)
+    )
+    def advance(words, keys, step0s, *, seg, collect):
+        s = words.shape[0]
+        keys = jax.vmap(lambda k: chain_key(k, 0))(keys)
+        samples, acc, state = (
+            unfold(x, s) for x in run_pallas(
+                engine, keys, target, fold(words), seg,
+                step0s.astype(jnp.int32), parse_collect(collect), chunk=seg,
             )
-            acc = acc.reshape(s, b, h, w)
-            words_out = samples[:, -1]
-            # the engine's Gibbs pseudo-likelihood of the final state
-            logit = jax.vmap(target.conditional_logit)(words_out)
-            logp = jnp.where(
-                words_out == 1,
-                jax.nn.log_sigmoid(logit),
-                jax.nn.log_sigmoid(-logit),
-            ).astype(jnp.float32)
-            if collect != "all":
-                samples = samples[:, :0]
-            return samples, words_out, logp, acc
+        )
+        return samples, state, final_logp(update, target, state), acc
 
     return advance
 

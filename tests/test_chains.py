@@ -165,6 +165,61 @@ class TestChainsSoloParity:
         with pytest.raises(ValueError):
             samplers.EngineConfig(num_chains=0)
 
+    @pytest.mark.parametrize("randomness", ["fused", "cim"])
+    @pytest.mark.parametrize("update", ["mh", "gibbs"])
+    def test_pallas_executor_per_chain_step0_equals_solo(
+        self, update, randomness
+    ):
+        """The Pallas executor given C keys and a (C,) step0 of unequal
+        offsets returns, under collect "all" and "last", what C solo runs
+        at those offsets return, bit for bit — over several chunks, so
+        each chunk's start adds to every chain's base.  The serving
+        tier's packed advance relies on exactly this."""
+        from repro.samplers import engine as eng_mod
+        from repro.samplers.randomness import chain_keys
+
+        if update == "mh":
+            target, init = _mh_target()
+            run, fold, unfold = (
+                eng_mod.run_pallas_mh, eng_mod.fold_mh, eng_mod.unfold_mh
+            )
+        else:
+            target, init = _gibbs_target()
+            run, fold, unfold = (
+                eng_mod.run_pallas_gibbs, eng_mod.fold_gibbs,
+                eng_mod.unfold_gibbs,
+            )
+        engine = _engine(
+            update=update, randomness=randomness, execution="pallas",
+            chunk_steps=4,
+        )
+        key, n_steps, offsets = jax.random.PRNGKey(5), 10, (0, 7, 12)
+        c = len(offsets)
+        for collect in ("all", "last"):
+            samples, acc, words = (
+                unfold(x, c) for x in run(
+                    engine, chain_keys(key, c), target, fold(_bcast(init, c)),
+                    n_steps, jnp.asarray(offsets, jnp.int32),
+                    samplers.parse_collect(collect),
+                )
+            )
+            logp = eng_mod.final_logp(update, target, words)
+            for i, s0 in enumerate(offsets):
+                solo = engine.run(
+                    key, target, n_steps, init, chain_id=i, step0=s0,
+                    collect=collect,
+                )
+                for got, want in (
+                    (samples[i], solo.samples),
+                    (acc[i], solo.accept_count),
+                    (words[i], solo.final_words),
+                    (logp[i], solo.final_logp),
+                ):
+                    assert got.shape == want.shape
+                    np.testing.assert_array_equal(
+                        np.asarray(got), np.asarray(want)
+                    )
+
 
 class TestWorkloadChains:
     @pytest.mark.parametrize("name", ["ising", "gmm"])

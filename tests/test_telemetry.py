@@ -537,7 +537,7 @@ class TestInstrumentation:
         compiled draw compiled: ``miss`` on the first chunk, ``hit`` on
         every later one (equal lengths, later starts, a second job); the
         same run staged inside ``jax.jit`` records no draw span."""
-        from repro.samplers.randomness import _chunk_program
+        from repro.samplers.engine import _draw_folded
 
         target, init = _mh_setup()
         engine = samplers.MHEngine(
@@ -548,7 +548,7 @@ class TestInstrumentation:
         plan = samplers.RunPlan(
             target=target, n_steps=24, init_words=init, seed=5
         )
-        _chunk_program.clear_cache()  # the first chunk must compile
+        _draw_folded.clear_cache()  # the first chunk must compile
         tr = telemetry.enable()
         engine.submit(plan)
         engine.submit(plan)
